@@ -225,6 +225,9 @@ def main() -> None:
     if args.n_replicas != "1" and not args.runtime:
         ap.error("--n-replicas applies to the --runtime sweep")
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.runtime:
         _run_runtime_sweep(args)
         return

@@ -89,7 +89,7 @@ class ModelConfig:
     # bounded redundant FLOPs for zero attention collectives (§Perf).
     attn_replicate_tp: bool = False
     # Use the Pallas flash-attention kernel for full-sequence forward
-    # passes where no gradient is needed (prefill/serve). interpret=True
+    # passes where no gradient is needed (prefill/serve). Interpret mode
     # on CPU; compiled on TPU. The jnp path remains the training default
     # (it carries the custom flash backward).
     use_pallas_attention: bool = False

@@ -38,7 +38,6 @@ from typing import Callable
 
 import numpy as np
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -183,10 +182,10 @@ class ShardedHTSRL(ScanRuntimeBase):
         # carry donated like every scan runtime (see
         # engine.ScanRuntimeBase._program): params/opt-state/trajectory
         # shards update in place across the program boundary
-        return jax.jit(shard_map(body, mesh=self.mesh,
-                                 in_specs=(carry_specs,),
-                                 out_specs=(carry_specs, metric_specs),
-                                 check_rep=False),
+        return jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                     in_specs=(carry_specs,),
+                                     out_specs=(carry_specs, metric_specs),
+                                     check_vma=False),
                        donate_argnums=0)
 
     def _finalize(self, carry):
@@ -203,10 +202,10 @@ class ShardedHTSRL(ScanRuntimeBase):
             dg_spec, _, _, buf_spec, j_spec = self._carry_specs(carry)
             slot_spec = {k: (P(self.axis) if k == "bootstrap_obs"
                              else P(None, self.axis)) for k in carry[3]}
-            wrap = lambda f: jax.jit(shard_map(
+            wrap = lambda f: jax.jit(jax.shard_map(
                 f, mesh=self.mesh,
                 in_specs=(dg_spec, slot_spec, P()),
-                out_specs=dg_spec, check_rep=False))
+                out_specs=dg_spec, check_vma=False))
             self._final_prog = mesh_runtime.make_ring_drain(
                 self._learn, self.cfg.staleness, wrap=wrap)
         dg, env_state, obs, buf, j = carry

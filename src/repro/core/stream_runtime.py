@@ -103,7 +103,7 @@ class StreamRuntime:
     def _build(self) -> None:
         if self._built:
             return
-        from repro.launch.mesh import as_shardings, use_mesh
+        from repro.launch.mesh import as_shardings
         mesh, opt = self.mesh, self.opt
         step_fn = learner.make_train_step(self.model_config, opt,
                                           self.cfg.algorithm,
@@ -123,7 +123,7 @@ class StreamRuntime:
         out_specs = (dg_specs,
                      jax.tree.map(lambda _: P(),
                                   jax.eval_shape(step_fn, dg0, probe)[1]))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             self._jstep = jax.jit(
                 step_fn,
                 in_shardings=as_shardings(mesh, (dg_specs, b_specs)),

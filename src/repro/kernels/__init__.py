@@ -7,8 +7,9 @@ Each kernel package exposes three layers:
   ops.py     — the jit'd public wrapper that auto-routes per backend.
 
 Routing: on TPU the Pallas kernel runs compiled; anywhere else it runs in
-``interpret=True`` mode (bit-faithful to the kernel semantics, slow), or
-callers can force the jnp oracle with ``use_pallas=False``.
+interpret mode (faithful to the kernel's arithmetic, slow, and blind to
+what the TPU compiler refuses — tests/test_tpu_compile.py covers that),
+or callers can force the jnp oracle by turning ``use_pallas`` off.
 ``resolve_backend`` centralizes that decision so the three wrappers stay
 in sync.
 """

@@ -12,7 +12,7 @@ BlockSpecs (VMEM tiles):
 Default Bq=Bk=128 and Dh in {64,128,256}: the qk^T tile is 128x128 (MXU
 native), VMEM footprint ~ (Bq*Dh + 2*Bk*Dh + Bq*Bk) * 4B  < 1 MB.
 
-Targets TPU; validated on CPU via interpret=True against ref.py.
+Targets TPU; validated on CPU in interpret mode against ref.py.
 """
 from __future__ import annotations
 

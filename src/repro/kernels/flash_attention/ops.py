@@ -2,7 +2,7 @@
 
 Accepts the model's (B, S, H, Dh) layout, transposes to the kernel's
 (B, H, S, Dh), pads the sequence to a block multiple, and dispatches to
-the Pallas kernel (interpret=True on CPU) or the jnp oracle.
+the Pallas kernel (interpret mode on CPU) or the jnp oracle.
 
 Gradients: the Pallas forward carries a ``jax.custom_vjp`` whose
 backward differentiates the jnp oracle on the saved (q, k, v) — the

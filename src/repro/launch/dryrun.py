@@ -28,8 +28,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from repro.configs.base import get_config, list_configs  # noqa: E402
 from repro.core import delayed_grad, learner  # noqa: E402
 from repro.launch import specs as specs_mod  # noqa: E402
-from repro.launch.mesh import (as_shardings, make_production_mesh,  # noqa: E402
-                               use_mesh)
+from repro.launch.mesh import as_shardings, make_production_mesh  # noqa: E402
 from repro.models import backbone  # noqa: E402
 from repro.optim import rmsprop, adam  # noqa: E402
 from repro.roofline import analysis, hlo_cost  # noqa: E402
@@ -74,7 +73,7 @@ def lower_one(arch: str, shape_name: str, mesh_name: str,
     opt = rmsprop(7e-4, eps=1e-5) if opt_name == "rmsprop" else adam(1e-4)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             batch = specs_mod.train_batch_specs(cfg, shape)
             dg_abs = jax.eval_shape(

@@ -2,12 +2,8 @@
 
 A FUNCTION, not a module constant — importing this module must never touch
 jax device state (smoke tests run on 1 CPU device; only dryrun.py forces
-512 host devices).
-
-Also hosts the version-compat shims: ``jax.sharding.AxisType`` and
-``jax.set_mesh`` only exist on newer jax; on the pinned 0.4.x the plain
-mesh plus the ``Mesh`` context manager provide identical semantics for
-our (fully ``Auto``) usage.
+512 host devices). Every mesh axis is ``Auto``: shardings propagate from
+params and inputs, pinned where needed by ``sharding.constraints``.
 """
 from __future__ import annotations
 
@@ -15,11 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):   # jax >= 0.5
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,18 +28,11 @@ def make_host_mesh():
     return _make_mesh((n,), ("data",))
 
 
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` for PartitionSpec resolution:
-    ``jax.set_mesh`` where available, the Mesh context manager otherwise."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh   # jax.sharding.Mesh is itself a context manager
-
-
 def as_shardings(mesh, spec_tree):
     """PartitionSpec pytree -> NamedSharding pytree for jit in/out_shardings
-    (jax 0.4.x rejects raw PartitionSpecs there; NamedSharding works on
-    every version). PartitionSpec subclasses tuple, so mark it as a leaf."""
+    (a raw PartitionSpec resolves only against the mesh in context at CALL
+    time; a NamedSharding carries its mesh, so the jitted step runs
+    anywhere). PartitionSpec subclasses tuple, so mark it as a leaf."""
     from jax.sharding import NamedSharding, PartitionSpec
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s) if isinstance(s, PartitionSpec)

@@ -26,6 +26,7 @@ import time
 import numpy as np
 
 from repro import api
+from repro.launch.cache import enable_compile_cache
 
 
 def result_digest(params, rewards, episode_returns) -> str:
@@ -74,6 +75,7 @@ def main() -> None:
                     help="re-run each tenant solo and fail unless the "
                     "pooled digests match (determinism smoke)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     specs = [api.load(p) for p in args.spec]
     if args.intervals is not None:

@@ -27,6 +27,7 @@ import sys
 import numpy as np
 
 from repro import api
+from repro.launch.cache import enable_compile_cache
 
 
 def _apply_set(canon: dict, assignment: str) -> None:
@@ -144,6 +145,7 @@ def main() -> None:
     ap.add_argument("--print-spec", action="store_true",
                     help="print the final canonical spec JSON and exit")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.env is None and args.spec is None:
         args.env = "catch"
 

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.core import determinism, learner
+from repro.launch.cache import enable_compile_cache
 from repro.models import backbone
 
 
@@ -78,6 +79,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.spec:
         serve_policy(args)

@@ -29,6 +29,7 @@ from repro import api
 from repro.checkpoint import io as ckpt_io
 from repro.core import delayed_grad
 from repro.core.engine import TrainState
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
@@ -57,6 +58,7 @@ def main():
                          "is fast-forwarded to the resumed step)")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.ckpt_every and not args.ckpt_dir:
         ap.error("--ckpt-every requires --checkpoint-dir")
     if args.resume and not args.ckpt_dir:
