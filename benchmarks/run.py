@@ -44,7 +44,6 @@ MODULES = [
     "tab5_sync_interval",
     "tabA1_correction",
     "tabA2_impl_sps",       # (engine_sps backs it; full sweep via --runtime)
-    "profile_hot_path",     # host runtime per-phase breakdown
     "staleness_sweep",      # throughput-vs-staleness frontier (K sweep)
     "roofline_table",
 ]

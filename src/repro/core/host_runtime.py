@@ -79,7 +79,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import delayed_grad, determinism
+from repro.core import delayed_grad, determinism, spans
 from repro.core.buffers import SlabRing
 from repro.core.engine import (HTSConfig, RunResult, TrainState,
                                register_runtime)
@@ -104,7 +104,9 @@ class HostConfig:
     # StepTimeModel sampled per update index — deterministic like
     # step_time, so throughput experiments are replayable
     learner_time: "float | StepTimeModel" = 0.0
-    profile: bool = False            # accumulate per-phase wall times
+    # accumulate per-phase waits and dispatch counts into
+    # ``runtime.profile``, and trace the actor and stepper dispatches
+    profile: bool = False
 
 
 @register_runtime("host")
@@ -162,8 +164,7 @@ class HostHTSRL:
         self._faults = faults
         self._built = False
         self.dg = None    # built lazily: run() always starts via init()
-        self.profile: Dict[str, float] = {}
-        self._prof_lock = threading.Lock()
+        self._phases = spans.Phases(self.host.profile)
         # reporting-only live observer: called by the coordinator as
         # ``on_interval(j, {"rewards": (alpha, n_envs), "dones": ...})``
         # the moment interval j's slab is complete (repro.api.Session
@@ -217,7 +218,8 @@ class HostHTSRL:
         def step_batch(env_states, actions, ids, ts, table):
             keys = jax.vmap(jax.random.wrap_key_data)(table[ts, ids])
             sel = jax.tree.map(lambda x: x[ids], env_states)
-            ns, nobs, r, d = venv.step(sel, actions, keys)
+            with jax.named_scope(spans.ENV_STEP):
+                ns, nobs, r, d = venv.step(sel, actions, keys)
             env_states = jax.tree.map(
                 lambda full, rows: full.at[ids].set(rows), env_states, ns)
             return env_states, nobs, r, d
@@ -239,7 +241,8 @@ class HostHTSRL:
         def stream_apply(params_prev, opt_state, step, params, grads):
             dg = delayed_grad.DelayedGradState(params, params_prev,
                                                opt_state, step)
-            return delayed_grad.update(dg, grads, self.opt)
+            with jax.named_scope(spans.OPTIMIZER):
+                return delayed_grad.update(dg, grads, self.opt)
 
         # theta_{j-K} (the history's oldest slot) and the old opt state
         # are dead once the update is applied, so they are donated and
@@ -291,11 +294,17 @@ class HostHTSRL:
         self.dones_log: list = []
         self.sps_steps = 0
         self.wall_time = 0.0
-        self.profile = {}
+        self._phases.clear()
 
-    def _prof(self, key: str, dt: float) -> None:
-        with self._prof_lock:
-            self.profile[key] = self.profile.get(key, 0.0) + dt
+    @property
+    def profile(self) -> Dict[str, float]:
+        """Per-segment totals with ``HostConfig(profile=True)``, empty
+        without: seconds the executors waited for actions
+        (``actor_wait``) and env steps (``env_step_wait``) summed over
+        threads, seconds the coordinator waited on the learner
+        (``learner_drain``), and the actor and stepper dispatch counts
+        (``actor_dispatches``, ``step_dispatches``)."""
+        return self._phases.totals
 
     # ------------------------------------------------------ continuation
     def _zero_traj(self):
@@ -513,7 +522,7 @@ class HostHTSRL:
     def _actor_loop(self) -> None:
         n = self.cfg.n_envs
         q = self._state_q
-        prof = self.host.profile
+        prof = self._phases
         while True:
             batch = self._drain_batch(q, q.get())
             if batch is None:
@@ -528,13 +537,12 @@ class HostHTSRL:
                 obs = np.concatenate([obs, np.repeat(obs[-1:], n - k, 0)])
             if self.host.actor_compute:
                 time.sleep(self.host.actor_compute * self.host.time_scale)
-            t0 = time.perf_counter() if prof else 0.0
-            actions, blp = self._actor_fwd(self._behavior, obs, ids, ts,
-                                           self._actor_table)
-            actions = np.asarray(actions)
-            blp = np.asarray(blp)
-            if prof:
-                self._prof("actor_forward", time.perf_counter() - t0)
+            with prof.span(spans.HOST_ACTOR_DISPATCH):
+                actions, blp = self._actor_fwd(self._behavior, obs, ids, ts,
+                                               self._actor_table)
+                actions = np.asarray(actions)
+                blp = np.asarray(blp)
+            prof.count("actor_dispatches")
             for i in range(k):
                 self._action_slots[ids[i]].put(
                     (int(actions[i]), float(blp[i])))
@@ -547,7 +555,7 @@ class HostHTSRL:
         (state, action, key)."""
         n = self.cfg.n_envs
         q = self._step_q
-        prof = self.host.profile
+        prof = self._phases
         while True:
             batch = self._drain_batch(q, q.get())
             if batch is None:
@@ -563,14 +571,13 @@ class HostHTSRL:
                 # raising mid-step (the exception surfaces from the env
                 # dispatch point, inside the stepper thread)
                 self._faults.fire("env_step", self._cur_j)
-            t0 = time.perf_counter() if prof else 0.0
-            self.env_states, nobs, r, d = self._step_batch(
-                self.env_states, acts, ids, ts, self._step_table)
-            nobs = np.asarray(nobs)
-            r = np.asarray(r)
-            d = np.asarray(d)
-            if prof:
-                self._prof("env_step_dispatch", time.perf_counter() - t0)
+            with prof.span(spans.HOST_STEP_DISPATCH):
+                self.env_states, nobs, r, d = self._step_batch(
+                    self.env_states, acts, ids, ts, self._step_table)
+                nobs = np.asarray(nobs)
+                r = np.asarray(r)
+                d = np.asarray(d)
+            prof.count("step_dispatches")
             for i in range(k):
                 self._step_slots[ids[i]].put(
                     (nobs[i], float(r[i]), float(d[i])))
@@ -601,7 +608,7 @@ class HostHTSRL:
     # --------------------------------------------------------- executors
     def _executor_loop(self, env_id: int) -> None:
         cfg, host = self.cfg, self.host
-        prof = host.profile
+        prof = self._phases
         while True:
             try:
                 self._start_barrier.wait()
@@ -616,27 +623,19 @@ class HostHTSRL:
             obs = self.obs_np[env_id]
             for t in range(cfg.alpha):
                 self._state_q.put((env_id, t, obs))
-                t0 = time.perf_counter() if prof else 0.0
-                got = self._action_slots[env_id].get()
+                got = prof.get("actor_wait", self._action_slots[env_id])
                 if got is _SHUTDOWN:
                     return              # a sibling worker died mid-interval
                 action, blp = got
-                if prof:
-                    self._prof("actor_wait", time.perf_counter() - t0)
                 if host.step_time is not None:
                     dt = host.step_time.sample(env_id, j * cfg.alpha + t,
                                                cfg.seed)
                     time.sleep(dt * host.time_scale)
-                    if prof:
-                        self._prof("sim_env_sleep", dt * host.time_scale)
                 self._step_q.put((env_id, t, action))
-                t0 = time.perf_counter() if prof else 0.0
-                got = self._step_slots[env_id].get()
+                got = prof.get("env_step_wait", self._step_slots[env_id])
                 if got is _SHUTDOWN:
                     return
                 nobs, r, d = got
-                if prof:
-                    self._prof("env_step_wait", time.perf_counter() - t0)
                 slab["obs"][t, env_id] = obs
                 slab["actions"][t, env_id] = action
                 slab["rewards"][t, env_id] = r
@@ -653,9 +652,9 @@ class HostHTSRL:
         return self._segment(n_intervals)
 
     def _run_intervals(self, n_intervals: int) -> None:
-        cfg, host = self.cfg, self.host
+        cfg = self.cfg
         K = cfg.staleness
-        prof = host.profile
+        prof = self._phases
         self._spawn_pools()
         try:
             for j in range(self.j, self.j + n_intervals):
@@ -668,10 +667,9 @@ class HostHTSRL:
                 # (DESIGN.md §4). With K > 1 that gradient was dispatched
                 # K intervals ago, so a learner slower than one interval
                 # no longer stalls every interval.
-                t0 = time.perf_counter() if prof else 0.0
-                jax.block_until_ready(self.dg)
-                if prof:
-                    self._prof("learner_drain", time.perf_counter() - t0)
+                with (spans.span(spans.HOST_LEARNER_DRAIN),
+                      prof.timed("learner_drain")):
+                    jax.block_until_ready(self.dg)
                 slab, boot = self._slabs.write_view(j)
                 self._cur_j = j
                 self._cur_slab, self._cur_boot = slab, boot
@@ -689,27 +687,22 @@ class HostHTSRL:
                     # parked on its ready gate, or a dying sim-learner
                     # thread could strand the coordinator forever
                     ent = self._pending[0]
-                    if ent["ready"] is not None:
-                        t0 = time.perf_counter() if prof else 0.0
-                        ent["ready"].wait()
-                        if prof:
-                            self._prof("sim_learner_wait",
-                                       time.perf_counter() - t0)
-                    self._pending.popleft()
-                    self.dg = self._apply_fn(
-                        self.dg.params_prev, self.dg.opt_state,
-                        self.dg.step, self.dg.params, ent["grads"])
-                t0 = time.perf_counter() if prof else 0.0
-                self._end_barrier.wait()            # executors finished
-                if prof:
-                    self._prof("interval_barrier",
-                               time.perf_counter() - t0)
+                    with spans.span(spans.HOST_APPLY_DISPATCH):
+                        if ent["ready"] is not None:
+                            ent["ready"].wait()
+                        self._pending.popleft()
+                        self.dg = self._apply_fn(
+                            self.dg.params_prev, self.dg.opt_state,
+                            self.dg.step, self.dg.params, ent["grads"])
+                with spans.span(spans.HOST_INTERVAL_BARRIER):
+                    self._end_barrier.wait()        # executors finished
                 # interval done: dispatch the gradient for D_j at theta_j
                 # immediately (by reference to the slab — only the small
                 # reporting streams are copied). It now has K intervals
                 # of rollout wall time before its apply blocks on it.
-                traj_j = self._slabs.as_traj(j)
-                grads = self._grad_fn(self._behavior, traj_j)
+                with spans.span(spans.HOST_GRAD_DISPATCH):
+                    traj_j = self._slabs.as_traj(j)
+                    grads = self._grad_fn(self._behavior, traj_j)
                 if self._faults is not None:
                     # "learner" site, at interval j's gradient dispatch:
                     # exc -> the learner dies here (coordinator raise);

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import algorithms
-from repro.core import delayed_grad
+from repro.core import delayed_grad, spans
 from repro.core.batch import pairwise_tree_sum
 from repro.core.engine import (HTSConfig, RunResult,  # noqa: F401 (re-export)
                                ScanRuntimeBase, register_runtime)
@@ -97,19 +97,22 @@ def make_grad_sum_fn(policy_apply: Callable, cfg: HTSConfig,
     def grad_sum(params, traj):
         per = _split_envs(traj)
         n_local = jax.tree.leaves(per)[0].shape[0]
-        per_env = jax.vmap(g1, in_axes=(None, 0))(params, per)
-        per_env = jax.tree.map(lambda g: g.astype(jnp.float32), per_env)
         A = grad_accumulation
-        if A <= 1:
-            return jax.tree.map(pairwise_tree_sum, per_env)
-        if n_local % A:
+        if A > 1 and n_local % A:
             raise ValueError(
                 f"grad_accumulation={A} does not divide the local env "
                 f"count {n_local}")
-        sums = jax.tree.map(
-            lambda g: jax.vmap(pairwise_tree_sum)(
-                g.reshape((A, n_local // A) + g.shape[1:])), per_env)
-        return jax.tree.map(pairwise_tree_sum, sums)
+        with jax.named_scope(spans.PER_ENV_GRAD):
+            per_env = jax.vmap(g1, in_axes=(None, 0))(params, per)
+            per_env = jax.tree.map(lambda g: g.astype(jnp.float32),
+                                   per_env)
+        with jax.named_scope(spans.GRAD_REDUCE):
+            if A <= 1:
+                return jax.tree.map(pairwise_tree_sum, per_env)
+            sums = jax.tree.map(
+                lambda g: jax.vmap(pairwise_tree_sum)(
+                    g.reshape((A, n_local // A) + g.shape[1:])), per_env)
+            return jax.tree.map(pairwise_tree_sum, sums)
 
     return grad_sum
 
@@ -175,9 +178,10 @@ def make_learner_update(policy_apply: Callable, opt: Optimizer,
         bp = delayed_grad.behavior_params(dg)
         s = grad_sum(bp, traj)
         if axis_name is not None:
-            s = jax.tree.map(
-                lambda g: pairwise_tree_sum(
-                    jax.lax.all_gather(g, axis_name)), s)
+            with jax.named_scope(spans.GRAD_REDUCE):
+                s = jax.tree.map(
+                    lambda g: pairwise_tree_sum(
+                        jax.lax.all_gather(g, axis_name)), s)
         grads = jax.tree.map(
             lambda g, p: (g / denom).astype(p.dtype), s, bp)
         # The gradient/update boundary is a ROUNDING boundary of the
@@ -187,7 +191,8 @@ def make_learner_update(policy_apply: Callable, opt: Optimizer,
         # update (e.g. FMA-combining the divide with rmsprop's g*g) —
         # that shifts opt_state by ulps and the runtimes drift apart.
         grads = jax.lax.optimization_barrier(grads)
-        return delayed_grad.update(dg, grads, opt, skip=skip)
+        with jax.named_scope(spans.OPTIMIZER):
+            return delayed_grad.update(dg, grads, opt, skip=skip)
 
     return learn
 
@@ -224,8 +229,11 @@ def make_ring_drain(learn, staleness: int, wrap=None):
     between passes does not pin), while a single pass per dispatch
     compiles identically everywhere — the drain is reporting-only, so
     K extra dispatches cost nothing that matters."""
-    one_pass = (wrap or jax.jit)(
-        lambda dg, traj, skip: learn(dg, traj, skip=skip))
+    def one_pass_scoped(dg, traj, skip):
+        with jax.named_scope(spans.DRAIN):
+            return learn(dg, traj, skip=skip)
+
+    one_pass = (wrap or jax.jit)(one_pass_scoped)
 
     def drain(dg, buf, j):
         for p in range(staleness):
@@ -265,13 +273,15 @@ def make_hts_step(policy_apply: Callable, env: Env, opt: Optimizer,
         # consume yet, so their updates are skipped — run(n) still
         # reflects n updates because _finalize drains the K pending
         # passes)
-        dg_next = learn(dg, ring_read(buf_ring, K), skip=(j < K))
+        with jax.named_scope(spans.LEARNER):
+            dg_next = learn(dg, ring_read(buf_ring, K), skip=(j < K))
         # ---- rollout half: behavior policy is theta_j (pre-update)
         offset = (jax.lax.axis_index(axis_name) * cfg.n_envs
                   if axis_name is not None else 0)
-        traj, env_state, obs = rollout_interval(
-            policy_apply, env, dg.params, env_state, obs, master,
-            j * cfg.alpha, rcfg, env_offset=offset)
+        with jax.named_scope(spans.ROLLOUT):
+            traj, env_state, obs = rollout_interval(
+                policy_apply, env, dg.params, env_state, obs, master,
+                j * cfg.alpha, rcfg, env_offset=offset)
         metrics = {"rewards": traj["rewards"], "dones": traj["dones"]}
         return (dg_next, env_state, obs, ring_append(buf_ring, traj, K),
                 j + 1), metrics

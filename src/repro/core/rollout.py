@@ -25,7 +25,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import determinism
+from repro.core import determinism, spans
 from repro.envs.interfaces import Env
 
 
@@ -42,10 +42,11 @@ def actor_forward(policy_apply: Callable, params, obs, keys):
     actor runs this, and how observations were batched, cannot affect the
     result: the key is a pure function of (run_seed, env_id, step).
     """
-    logits, _ = policy_apply(params, obs)
-    actions = jax.vmap(determinism.sample_action)(keys, logits)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    blp = jnp.take_along_axis(logp, actions[:, None], axis=-1)[:, 0]
+    with jax.named_scope(spans.ACTOR_FORWARD):
+        logits, _ = policy_apply(params, obs)
+        actions = jax.vmap(determinism.sample_action)(keys, logits)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        blp = jnp.take_along_axis(logp, actions[:, None], axis=-1)[:, 0]
     return actions, blp
 
 
@@ -70,8 +71,9 @@ def rollout_interval(policy_apply: Callable, env: Env, params, env_state,
         step_keys = jax.vmap(
             lambda e: determinism.obs_key(master_key, e + 1_000_003, gstep)
         )(env_ids)
-        env_state, next_obs, reward, done = env.step(env_state, actions,
-                                                     step_keys)
+        with jax.named_scope(spans.ENV_STEP):
+            env_state, next_obs, reward, done = env.step(env_state, actions,
+                                                         step_keys)
         out = {"obs": obs, "actions": actions, "rewards": reward,
                "dones": done, "behavior_logprob": blp}
         return (env_state, next_obs), out

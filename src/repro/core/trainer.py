@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.checkpoint import io as ckpt_io
-from repro.core import evaluate
+from repro.core import evaluate, spans
 from repro.core.engine import Runtime, TrainState
 from repro.faults import FaultInjector, FaultPlan
 
@@ -295,8 +295,10 @@ class Trainer:
                 f"({os.path.basename(self.latest_checkpoint())}); pass "
                 f"resume=True to continue that run, or point "
                 f"checkpoint_dir at a fresh directory")
-        state, start, metric_state = (self._resume() if resume
-                                      else (None, 0, None))
+        state, start, metric_state = None, 0, None
+        if resume:
+            with spans.span(spans.FIT_RESUME):
+                state, start, metric_state = self._resume()
         stream = evaluate.ReturnStream(cfg.n_envs)
         if metric_state is not None:
             stream.load_state_dict(metric_state)
@@ -329,8 +331,9 @@ class Trainer:
             try:
                 # only the final segment pays the reporting-only trailing
                 # learner pass; intermediate segments just stream metrics
-                out = self.runtime.run_from(
-                    state, chunk, finalize=(done + chunk >= n_intervals))
+                with spans.span(spans.FIT_SEGMENT):
+                    out = self.runtime.run_from(
+                        state, chunk, finalize=(done + chunk >= n_intervals))
                 if plan is not None:
                     # BEFORE the capsule is saved: a diverged step must
                     # never become durable
@@ -348,8 +351,9 @@ class Trainer:
                       f"{delay:.3f}s backoff", file=sys.stderr)
                 time.sleep(delay)
                 r0 = time.perf_counter()
-                state, done, mstate = self._recover(
-                    entry, start, entry_metrics)
+                with spans.span(spans.FIT_RECOVER):
+                    state, done, mstate = self._recover(
+                        entry, start, entry_metrics)
                 stream = evaluate.ReturnStream(cfg.n_envs)
                 if mstate is not None:
                     stream.load_state_dict(mstate)
@@ -362,16 +366,19 @@ class Trainer:
                 })
                 continue
             consec = 0
-            if self.on_interval is not None:
-                for i, metrics in out.interval_metrics():
-                    self.on_interval(done + i, metrics)
+            with spans.span(spans.FIT_STREAM):
+                if self.on_interval is not None:
+                    for i, metrics in out.interval_metrics():
+                        self.on_interval(done + i, metrics)
+                stream.extend(out.rewards, out.dones)
             done += chunk
-            state = self.runtime.state()
-            stream.extend(out.rewards, out.dones)
+            with spans.span(spans.FIT_CAPTURE):
+                state = self.runtime.state()
             segs.append((done, out.rewards, out.dones, out.steps))
             steps_executed += out.steps
             if self.checkpoint_dir:
-                self._save(state, done, stream)
+                with spans.span(spans.FIT_SAVE):
+                    self._save(state, done, stream)
             if self.on_segment is not None:
                 self.on_segment(done, out)
         if out is None:
