@@ -35,7 +35,8 @@ import jax.numpy as jnp
 
 from repro import algorithms
 from repro.core import delayed_grad, spans
-from repro.core.batch import pairwise_tree_sum
+from repro.core.batch import (bit_reversed_order, halves_tree_sum,
+                              pairwise_tree_sum)
 from repro.core.engine import (HTSConfig, RunResult,  # noqa: F401 (re-export)
                                ScanRuntimeBase, register_runtime)
 from repro.core.rollout import RolloutConfig, rollout_interval
@@ -83,6 +84,15 @@ def make_grad_sum_fn(policy_apply: Callable, cfg: HTSConfig,
     SUMS, and the single divide by the global batch happens in
     make_grad_fn / make_learner_update.
 
+    With a power-of-two local env count the trajectory's env axis is
+    permuted to bit-reversed order before the vmap (a gather on the
+    trajectory, never on the per-env stack), and ``halves_tree_sum``
+    reduces the flat stack in contiguous halves: the same adds in the
+    same order, so the same float as the strided tree, with or without
+    accumulation (blocks are exact subtrees). Any other count takes
+    the strided tree. The scopes ``tree_halves`` and ``tree_strided``
+    inside ``grad_reduce`` say which path ran.
+
     The backward is deliberately NOT scanned block-by-block: a width-1
     vmap inside ``lax.scan`` gets simplified to the unbatched lowering,
     whose matmuls take a different (gemv) accumulation path than the
@@ -102,17 +112,28 @@ def make_grad_sum_fn(policy_apply: Callable, cfg: HTSConfig,
             raise ValueError(
                 f"grad_accumulation={A} does not divide the local env "
                 f"count {n_local}")
+        halves = (n_local & (n_local - 1)) == 0
+        if halves:
+            order = bit_reversed_order(n_local)
+            with jax.named_scope(spans.GRAD_REDUCE), \
+                    jax.named_scope(spans.TREE_HALVES):
+                per = jax.tree.map(lambda x: x[order], per)
         with jax.named_scope(spans.PER_ENV_GRAD):
             per_env = jax.vmap(g1, in_axes=(None, 0))(params, per)
             per_env = jax.tree.map(lambda g: g.astype(jnp.float32),
                                    per_env)
         with jax.named_scope(spans.GRAD_REDUCE):
-            if A <= 1:
-                return jax.tree.map(pairwise_tree_sum, per_env)
-            sums = jax.tree.map(
-                lambda g: jax.vmap(pairwise_tree_sum)(
-                    g.reshape((A, n_local // A) + g.shape[1:])), per_env)
-            return jax.tree.map(pairwise_tree_sum, sums)
+            if halves:
+                with jax.named_scope(spans.TREE_HALVES):
+                    return jax.tree.map(halves_tree_sum, per_env)
+            with jax.named_scope(spans.TREE_STRIDED):
+                if A <= 1:
+                    return jax.tree.map(pairwise_tree_sum, per_env)
+                sums = jax.tree.map(
+                    lambda g: jax.vmap(pairwise_tree_sum)(
+                        g.reshape((A, n_local // A) + g.shape[1:])),
+                    per_env)
+                return jax.tree.map(pairwise_tree_sum, sums)
 
     return grad_sum
 

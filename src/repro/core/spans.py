@@ -56,6 +56,12 @@ DRAIN = "hts.drain"              # the reporting-only trailing pass
 SCOPES = (ROLLOUT, ACTOR_FORWARD, ENV_STEP, LEARNER, PER_ENV_GRAD,
           GRAD_REDUCE, OPTIMIZER, DRAIN)
 
+# ---- inside grad_reduce, the path the per-env tree sum took
+# (core/batch.py); kept out of SCOPES, the names the benchmark's trace
+# reader knows
+TREE_HALVES = "tree_halves"      # contiguous halves, power-of-two count
+TREE_STRIDED = "tree_strided"    # strided pairs, any other count
+
 
 def span(name: str):
     """A host span named ``name`` around a ``with`` block."""

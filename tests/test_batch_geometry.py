@@ -20,6 +20,7 @@ Three layers of pinning:
 """
 import json
 import os
+import re
 import socket
 import subprocess
 import sys
@@ -32,9 +33,12 @@ import pytest
 
 from repro import api, models
 from repro.core import engine
-from repro.core.batch import BatchConfig, pairwise_tree_sum
+from repro.core import batch as batch_mod
+from repro.core import mesh_runtime, spans
+from repro.core.batch import (BatchConfig, bit_reversed_order,
+                              halves_tree_sum, pairwise_tree_sum)
 from repro.core.engine import HTSConfig
-from repro.envs import catch
+from repro.envs import catch, gridmaze
 from repro.optim import rmsprop
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -160,6 +164,123 @@ def test_pairwise_tree_sum_subtree_property():
         assert float(pairwise_tree_sum(sums)) == float(flat), blocks
 
 
+def _wide(rng, shape):
+    """float32 values spread over 18 decades, both signs."""
+    return (rng.standard_normal(shape)
+            * 10.0 ** rng.integers(-9, 9, shape)).astype(np.float32)
+
+
+def _cnn(**widths):
+    env = gridmaze.make()
+    return models.get_policy("cnn", env, conv_sizes=(3, 3, 3),
+                             conv_strides=(1, 1, 1), **widths)
+
+
+def _same_bits(a, b) -> bool:
+    return all(np.array_equal(np.asarray(x).view(np.uint32),
+                              np.asarray(y).view(np.uint32))
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+def test_bit_reversed_order():
+    assert list(bit_reversed_order(1)) == [0]
+    assert list(bit_reversed_order(8)) == [0, 4, 2, 6, 1, 5, 3, 7]
+    for n in (2, 64, 2048):
+        order = bit_reversed_order(n)
+        assert sorted(order) == list(range(n))
+        assert list(order[order]) == list(range(n))     # an involution
+    with pytest.raises(ValueError, match="power of two"):
+        bit_reversed_order(48)
+    with pytest.raises(ValueError, match="power-of-two"):
+        halves_tree_sum(jnp.zeros((6, 2)))
+
+
+@pytest.mark.parametrize("n", [2 ** e for e in range(12)])
+def test_halves_tree_sum_is_pairwise_tree_sum_bit_for_bit(n, monkeypatch):
+    """On a stack stored in bit-reversed order the contiguous-halves tree
+    computes ``pairwise_tree_sum``'s float, bit for bit: on each of the
+    cnn policy's leaves (narrowed, so that a stack of 2048 stays small)
+    and three of its leaves at the paper's widths, and for every number
+    of levels per pass from 1 to 11 on those leaves side by side (the
+    adds run along the env axis alone, one column at a time)."""
+    rng = np.random.default_rng(n)
+    params = jax.eval_shape(
+        lambda: _cnn(conv_filters=(4, 8, 8), hidden=8).init(
+            jax.random.key(0)))
+    shapes = [l.shape for l in jax.tree.leaves(params)]
+    shapes += [(3,), (576, 8), (3, 3, 3, 32)]
+    stack = [_wide(rng, (n,) + shape) for shape in shapes]
+    want = jax.jit(lambda s: [pairwise_tree_sum(x) for x in s])(stack)
+    order = bit_reversed_order(n)
+    stored = [x[order] for x in stack]
+    got = jax.jit(lambda s: [halves_tree_sum(x) for x in s])(stored)
+    assert _same_bits(want, got)
+    columns = np.concatenate([x.reshape(n, -1) for x in stored], axis=1)
+    want = np.concatenate([np.asarray(w).reshape(-1) for w in want])
+    for k in range(1, 12):
+        monkeypatch.setattr(batch_mod, "HALVES_PER_PASS", k)
+        got = jax.jit(lambda s: halves_tree_sum(s))(columns)
+        assert _same_bits(want, got), k
+
+
+def _old_grad_sum(policy_apply, cfg, A):
+    """The strided formulation: vmap in natural env order, then the
+    adjacent-pairwise tree (per block, then over the blocks, for A > 1)."""
+    g1 = jax.grad(lambda p, t: mesh_runtime._interval_loss(
+        policy_apply, p, t, cfg)[0])
+
+    def grad_sum(params, traj):
+        per = mesh_runtime._split_envs(traj)
+        g = jax.vmap(g1, in_axes=(None, 0))(params, per)
+        g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
+        if A == 1:
+            return jax.tree.map(pairwise_tree_sum, g)
+        n = jax.tree.leaves(g)[0].shape[0]
+        sums = jax.tree.map(lambda x: jax.vmap(pairwise_tree_sum)(
+            x.reshape((A, n // A) + x.shape[1:])), g)
+        return jax.tree.map(pairwise_tree_sum, sums)
+    return grad_sum
+
+
+def _random_traj(n, alpha, seed):
+    rng = np.random.default_rng(seed)
+    return {
+        "obs": jnp.asarray(rng.random((alpha, n, 9, 9, 3), np.float32)),
+        "actions": jnp.asarray(rng.integers(0, 4, (alpha, n)), jnp.int32),
+        "rewards": jnp.asarray(rng.standard_normal((alpha, n)),
+                               jnp.float32),
+        "dones": jnp.asarray(rng.random((alpha, n)) < 0.2, jnp.float32),
+        "behavior_logprob": jnp.asarray(-rng.random((alpha, n)),
+                                        jnp.float32),
+        "bootstrap_obs": jnp.asarray(rng.random((n, 9, 9, 3), np.float32)),
+    }
+
+
+@pytest.mark.parametrize("n,A", [(8, 1), (8, 2), (8, 4), (64, 1),
+                                 (64, 2), (64, 4), (48, 1), (48, 2),
+                                 (48, 4)])
+def test_grad_sum_keeps_the_strided_bits(n, A):
+    """``make_grad_sum_fn`` on the cnn policy gives the strided
+    formulation's gradient sum bit for bit: bit-reversed env order and
+    contiguous halves at a power-of-two count (with or without
+    accumulation), the strided tree itself at 48 envs."""
+    policy = _cnn()
+    params = policy.init(jax.random.key(1))
+    cfg = HTSConfig(alpha=5, n_envs=n, seed=3)
+    traj = _random_traj(n, cfg.alpha, seed=n + A)
+    new = jax.jit(mesh_runtime.make_grad_sum_fn(policy.apply, cfg, A))
+    old = jax.jit(_old_grad_sum(policy.apply, cfg, A))
+    assert _same_bits(old(params, traj), new(params, traj))
+    paths = re.findall(r'op_name="([^"]*)"',
+                       new.lower(params, traj).compile().as_text())
+    path = spans.TREE_HALVES if n & (n - 1) == 0 else spans.TREE_STRIDED
+    for name in (spans.TREE_HALVES, spans.TREE_STRIDED):
+        under = [p for p in paths if name in p.split("/")]
+        assert bool(under) == (name == path), name
+        assert all(spans.GRAD_REDUCE in p.split("/")[:p.split("/").index(
+            name)] for p in under), name
+
+
 # --------------------------------------------------- spec / fingerprint
 def test_spec_validates_geometry_eagerly():
     with pytest.raises(ValueError, match="batch.n_replicas=3"):
@@ -254,7 +375,7 @@ _TWO_DEVICE_SCRIPT = textwrap.dedent("""
     from repro import models
     from repro.core import engine
     from repro.core.engine import HTSConfig
-    from repro.envs import catch
+    from repro.envs import catch, gridmaze
     from repro.optim import rmsprop
     env1 = catch.make()
     cfg = HTSConfig(alpha=5, n_envs=4, seed=3)

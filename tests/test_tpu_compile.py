@@ -115,7 +115,11 @@ def test_cnn_learner_update_compiles_at_full_width(one_chip):
                                     skip).compile()
     mem = compiled.memory_analysis()
     print(mem)
-    # the per-env gradient stack alone is 512 x 354,309 x 4 B
-    assert mem.temp_size_in_bytes >= 512 * 354_309 * 4
+    # the per-env gradient stack is 512 x 354,309 x 4 B. The tree sum's
+    # first pass may fuse into the producers of its smaller leaves, but
+    # the fc layer's per-env gradients (512 x 576 x 512 x 4 B) are held
+    # whole, and the sum holds no second copy of the stack
+    assert mem.temp_size_in_bytes >= 512 * 576 * 512 * 4
+    assert mem.temp_size_in_bytes <= 512 * 354_309 * 4
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
