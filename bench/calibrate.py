@@ -38,19 +38,21 @@ def training(cell, seeds, control_seeds, fault_seeds, control):
     out = {"program": [], "control": [], "faults": {f: [] for f in faults}}
     run_dir = ROOT / "bench_runs" / cell.name / "calibrate"
     spec = cell.spec_dict()
-    env = ref_envs.make(spec["env"]["name"])
+    env = ref_envs.make(spec["env"]["name"], spec["env"].get("kwargs", {}),
+                        cell.bench_dir)
     for i in range(seeds):
         t = time.perf_counter()
         spec, env, params0, rec, session, _ = fit.first_steps(
             cell, SEED0 + i, run_dir)
         del session
         gc.collect()
-        nums = fit.check(spec, env, params0, rec)
+        nums = fit.check(spec, env, params0, rec, cell.bench_dir)
         out["program"].append({"seed": SEED0 + i, **nums})
         print(f"[program] seed {SEED0 + i}: {nums} "
               f"({time.perf_counter() - t:.1f}s)", file=sys.stderr,
               flush=True)
-    _, apply = nets.make(S.ref_policy(spec), env.obs_shape, env.n_actions)
+    _, apply = nets.make(S.ref_policy(spec), env.obs_shape, env.n_actions,
+                         cell.bench_dir)
     hp, n_envs = S.ref_hp(spec), spec["hts"]["n_envs"]
     runs = [("control", None, control, control_seeds)] + [
         (f, f, "highest", fault_seeds) for f in faults]
@@ -59,7 +61,8 @@ def training(cell, seeds, control_seeds, fault_seeds, control):
             t = time.perf_counter()
             seed = SEED0 + 100 + i
             params0 = S.host(S.make_weights(spec, env.obs_shape,
-                                            env.n_actions, seed))
+                                            env.n_actions, seed,
+                                            cell.bench_dir))
             rec = hts.simulate(apply, env, params0, hp, n_envs,
                                fit.CHECK_INTERVALS, mode, fault=fault)
             nums = hts.check(apply, env, params0, hp, rec)

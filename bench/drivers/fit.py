@@ -57,8 +57,10 @@ def first_steps(cell, seed: int, run_dir, profile: bool = False):
     spec["checkpoint"] = {"dir": str(run_dir / "ckpt"),
                           "every": int(cell.traffic["ckpt_every"]),
                           "keep": 2}
-    env = ref_envs.make(spec["env"]["name"])
-    params = S.make_weights(spec, env.obs_shape, env.n_actions, seed)
+    env = ref_envs.make(spec["env"]["name"], spec["env"].get("kwargs", {}),
+                        cell.bench_dir)
+    params = S.make_weights(spec, env.obs_shape, env.n_actions, seed,
+                            cell.bench_dir)
     params0 = S.host(params)
     runtime_kwargs = {}
     if spec["runtime"]["name"] == "host":
@@ -112,9 +114,10 @@ def segment_tie(runtime, capsule, every: int) -> int:
     return sum(_bits_differ(x, y) for x, y in zip(a, b))
 
 
-def check(spec, env, params0, rec) -> dict:
+def check(spec, env, params0, rec, bench_dir) -> dict:
     """The reference's replay of a record (``hts.check``)."""
-    _, apply = nets.make(S.ref_policy(spec), env.obs_shape, env.n_actions)
+    _, apply = nets.make(S.ref_policy(spec), env.obs_shape, env.n_actions,
+                         bench_dir)
     return hts.check(apply, env, params0, S.ref_hp(spec), rec)
 
 
@@ -166,7 +169,7 @@ def run(ctx) -> dict:
     gc.collect()
     shutil.rmtree(run_dir, ignore_errors=True)
     numbers = {"segment_mismatch": mismatch,
-               **check(spec, env, params0, rec)}
+               **check(spec, env, params0, rec, cell.bench_dir)}
     check_s = time.perf_counter() - t_check
 
     window_s = t1 - t0
@@ -182,7 +185,7 @@ def run(ctx) -> dict:
         "env_steps_per_s": rate,
         "flops_per_step": flops.train_step(S.ref_policy(spec),
                                            env.obs_shape, env.n_actions,
-                                           alpha),
+                                           alpha, cell.bench_dir),
         "device_kind": ctx["devices"][0].device_kind,
         "kind": "fit",
         "chips": len(ctx["devices"]),

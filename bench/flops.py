@@ -7,48 +7,44 @@ that step, and, once per interval of ``alpha`` steps, a forward of the
 bootstrap observation (no backward: its value is a constant). The
 backward of a layer costs its input gradient and its weight gradient,
 each as much as its forward, except that no gradient flows into the
-observation.
+observation. Each policy counts its own layers in its reference file
+(``bench/reference/net_<name>.py``), found by the policy's name.
 """
 from __future__ import annotations
 
+from pathlib import Path
 from typing import List, Tuple
 
-
-def cnn_layers(obs_shape, arch, n_actions) -> List[Tuple[str, float]]:
-    """(name, forward FLOPs for one observation) per layer."""
-    h, w, cin = obs_shape
-    out = []
-    for i, (f, k, s) in enumerate(zip(arch["conv_filters"],
-                                      arch["conv_sizes"],
-                                      arch["conv_strides"])):
-        h, w = (h - k) // s + 1, (w - k) // s + 1
-        out.append((f"conv{i}", 2.0 * h * w * k * k * cin * f))
-        cin = f
-    flat = h * w * cin
-    out.append(("fc", 2.0 * flat * arch["hidden"]))
-    out.append(("heads", 2.0 * arch["hidden"] * (n_actions + 1)))
-    return out
+from bench.harness import BENCH_DIR
+from bench.reference import nets
 
 
-def layers(policy: dict, obs_shape, n_actions):
-    if policy["name"] == "cnn":
-        return cnn_layers(obs_shape, policy["arch"], n_actions)
-    raise ValueError(f"no FLOP count for policy {policy['name']!r}")
+def layers(policy, obs_shape, n_actions, bench_dir: Path = BENCH_DIR
+           ) -> List[Tuple[str, float]]:
+    """(name, forward FLOPs for one observation) per layer, as the
+    policy's reference file counts them (``net_<name>.layers``)."""
+    return nets.load(policy["name"], bench_dir).layers(
+        obs_shape, policy["arch"], n_actions)
 
 
-def forward(policy, obs_shape, n_actions) -> float:
-    return sum(f for _, f in layers(policy, obs_shape, n_actions))
+def forward(policy, obs_shape, n_actions,
+            bench_dir: Path = BENCH_DIR) -> float:
+    return sum(f for _, f in layers(policy, obs_shape, n_actions,
+                                    bench_dir))
 
 
-def backward(policy, obs_shape, n_actions) -> float:
+def backward(policy, obs_shape, n_actions,
+             bench_dir: Path = BENCH_DIR) -> float:
     """Weight gradients of every layer, input gradients of all but the
     first."""
-    ls = layers(policy, obs_shape, n_actions)
+    ls = layers(policy, obs_shape, n_actions, bench_dir)
     return 2 * sum(f for _, f in ls) - ls[0][1]
 
 
-def train_step(policy, obs_shape, n_actions, alpha: int) -> float:
+def train_step(policy, obs_shape, n_actions, alpha: int,
+               bench_dir: Path = BENCH_DIR) -> float:
     """Model FLOPs per env step trained."""
-    fwd = forward(policy, obs_shape, n_actions)
-    return fwd + fwd + backward(policy, obs_shape, n_actions) + fwd / alpha
+    fwd = forward(policy, obs_shape, n_actions, bench_dir)
+    return (fwd + fwd + backward(policy, obs_shape, n_actions, bench_dir)
+            + fwd / alpha)
 

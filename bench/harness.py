@@ -11,10 +11,16 @@ A cell of ``BENCHMARK.json`` names a configuration and a traffic mix:
 * ``bench/limits/<cell>.json``     -- the limit of each number the
   correctness check compares, with the readings it was set from;
 * ``bench/metrics/<metric>.py``    -- one reader per per-layer metric,
-  ``read(record) -> float | None``.
+  ``read(record) -> float | None``;
+* ``bench/reference/env_<env>.py`` -- the plain reference of the env
+  the configuration names, ``make(**kwargs) -> RefEnv``;
+* ``bench/reference/net_<policy>.py`` -- the plain reference of the
+  policy it names, ``make(policy, obs_shape, n_actions)``, and its FLOP
+  count by layer, ``layers(obs_shape, arch, n_actions)``.
 
-Nothing here names a cell, configuration or metric: a later change adds
-files and ``BENCHMARK.json`` entries, and edits none of these.
+Nothing here names a cell, configuration, env, policy or metric: a later
+change adds files and ``BENCHMARK.json`` entries, and edits none of
+these.
 """
 from __future__ import annotations
 
@@ -54,11 +60,24 @@ def merge(base: dict, over: dict) -> dict:
     return out
 
 
+def load_file(folder: str, name: str, bench_dir: Path = BENCH_DIR,
+              prefix: str = ""):
+    """The module ``<bench_dir>/<folder>/<prefix><name>.py``; a name with
+    no such file is an error that lists the names found there."""
+    where = Path(bench_dir) / folder
+    path = where / f"{prefix}{name}.py"
+    if not path.is_file():
+        found = sorted(p.stem[len(prefix):] for p in where.glob(
+            f"{prefix}*.py") if not p.stem.startswith("_"))
+        raise LookupError(f"no {folder}/{prefix}{name}.py under "
+                          f"{bench_dir}; found {found}")
+    return _load_module(
+        path, f"bench_{folder}_{prefix}{name}".replace(".", "_"))
+
+
 def load_reader(name: str, bench_dir: Path = BENCH_DIR) -> Callable:
     """``read`` of ``bench/metrics/<name>.py``."""
-    mod = _load_module(bench_dir / "metrics" / f"{name}.py",
-                       f"bench_metric_{name.replace('.', '_')}")
-    return mod.read
+    return load_file("metrics", name, bench_dir).read
 
 
 @dataclass
@@ -72,6 +91,7 @@ class Cell:
     per_layer: List[dict]
     driver: Callable        # bench/drivers/<kind>.py: run(ctx) -> record
     readers: Dict[str, Callable]   # per-layer metric name -> read()
+    bench_dir: Path = BENCH_DIR    # where its references are found
 
     def spec_dict(self) -> dict:
         """The ExperimentSpec this cell runs: configuration, then the
@@ -101,9 +121,7 @@ class Benchmark:
         return load_reader(name, self.bench_dir)
 
     def driver(self, kind: str) -> Callable:
-        mod = _load_module(self.bench_dir / "drivers" / f"{kind}.py",
-                           f"bench_driver_{kind}")
-        return mod.run
+        return load_file("drivers", kind, self.bench_dir).run
 
     def cell(self, name: str) -> Cell:
         by_name = {w["name"]: w for w in self.doc["workloads"]}
@@ -133,7 +151,8 @@ class Benchmark:
                     limits=limits, chips=int(chips), end_to_end=e2e,
                     per_layer=per, driver=self.driver(traffic["kind"]),
                     readers={m["name"]: self.reader(m["name"])
-                             for m in per})
+                             for m in per},
+                    bench_dir=self.bench_dir)
 
 
 def read_per_layer(cell: Cell, record: dict) -> Dict[str, Optional[float]]:

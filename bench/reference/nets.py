@@ -1,5 +1,10 @@
-"""The policy, written plainly: the paper's conv trunk (appendix F:
-conv 32/64/64, fc 512, policy and value heads).
+"""The policies, written plainly, and the arithmetic they share.
+
+Each policy's reference is a file of its own, ``net_<name>.py`` beside
+this one: ``make(policy, obs_shape, n_actions) -> (init, apply)`` and
+``layers(obs_shape, arch, n_actions)``, its model FLOPs by layer
+(``bench.flops``); ``make`` and ``load`` here find it by the policy's
+name. ``apply(params, obs, mode)`` computes in ``mode``.
 
 ``mode`` names the arithmetic (``MODES``): ``highest`` is float32 at
 ``Precision.HIGHEST``, the reference; ``high`` is float32 at three
@@ -7,18 +12,18 @@ bfloat16 passes, ``one_pass`` at one (what a TPU does at its default
 precision, written out so that it reads the same on every platform),
 and ``default`` at the platform's default precision; ``bfloat16`` keeps
 every array, weights included, in bfloat16. The last four serve as
-lower-precision controls. ReLU's derivative at 0 is 0,
-as in the frameworks the paper used. Parameter names follow the
-layout the system under test takes, so the benchmark can hand it the
-weights it makes here.
+lower-precision controls. Parameter names follow the layout the system
+under test takes, so the benchmark can hand it the weights made here.
 """
 from __future__ import annotations
 
 import functools
-import math
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+
+from bench.harness import BENCH_DIR, load_file
 
 P = jax.lax.Precision
 BF16, F32 = jnp.bfloat16, jnp.float32
@@ -104,13 +109,15 @@ def _product(exact, x, w, mode):
     raise ValueError(f"no exact product in mode {mode!r}")
 
 
-def _dot(x, w, mode):
+def dot(x, w, mode):
+    """``x @ w`` in ``mode``."""
     if mode in ("default", "bfloat16"):
         return jnp.dot(x, w)
     return _product(_dot_exact, x, w, mode)
 
 
-def _conv(x, w, stride, mode):
+def conv(x, w, stride, mode):
+    """A VALID NHWC convolution in ``mode``."""
     if mode in ("default", "bfloat16"):
         return jax.lax.conv_general_dilated(
             x, w, (stride, stride), "VALID",
@@ -118,52 +125,14 @@ def _conv(x, w, stride, mode):
     return _product(_conv_exact(stride), x, w, mode)
 
 
-def cnn_init(key, obs_shape, n_actions, filters, sizes, strides, hidden):
-    """He-normal conv and fc weights, zero biases, a 0.01-scaled policy
-    head and a unit-scaled value head."""
-    ks = jax.random.split(key, len(filters) + 3)
-    h, w, cin = obs_shape
-    params = {}
-    for i, (f, k, s) in enumerate(zip(filters, sizes, strides)):
-        params[f"conv{i}_w"] = (jax.random.normal(ks[i], (k, k, cin, f))
-                                * math.sqrt(2.0 / (k * k * cin)))
-        params[f"conv{i}_b"] = jnp.zeros((f,))
-        h, w, cin = (h - k) // s + 1, (w - k) // s + 1, f
-    flat = h * w * cin
-    params["fc_w"] = (jax.random.normal(ks[-3], (flat, hidden))
-                      * math.sqrt(2.0 / flat))
-    params["fc_b"] = jnp.zeros((hidden,))
-    params["pi_w"] = jax.random.normal(ks[-2], (hidden, n_actions)) * 0.01
-    params["pi_b"] = jnp.zeros((n_actions,))
-    params["v_w"] = jax.random.normal(ks[-1], (hidden, 1))
-    params["v_b"] = jnp.zeros((1,))
-    return params
+def load(name: str, bench_dir: Path = BENCH_DIR):
+    """The module of policy ``name``: ``reference/net_<name>.py`` under
+    ``bench_dir``."""
+    return load_file("reference", name, bench_dir, prefix="net_")
 
 
-def cnn_apply(params, obs, strides, mode="highest"):
-    """obs (B, H, W, C) -> (logits (B, A), value (B,)), in ``mode``."""
-    dtype = arith(mode)
-    p = jax.tree.map(lambda a: a.astype(dtype), params)
-    x = obs.astype(dtype)
-    for i, s in enumerate(strides):
-        x = jax.nn.relu(_conv(x, p[f"conv{i}_w"], s, mode)
-                        + p[f"conv{i}_b"])
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(_dot(x, p["fc_w"], mode) + p["fc_b"])
-    logits = _dot(x, p["pi_w"], mode) + p["pi_b"]
-    value = (_dot(x, p["v_w"], mode) + p["v_b"])[:, 0]
-    return logits, value
-
-
-def make(policy: dict, obs_shape, n_actions):
+def make(policy: dict, obs_shape, n_actions, bench_dir: Path = BENCH_DIR):
     """(init(key), apply(params, obs, mode)) for a configuration's
-    ``policy`` block."""
-    if policy["name"] == "cnn":
-        a = policy["arch"]
-        init = lambda key: cnn_init(key, tuple(obs_shape), n_actions,
-                                    a["conv_filters"], a["conv_sizes"],
-                                    a["conv_strides"], a["hidden"])
-        apply = lambda p, obs, mode="highest": cnn_apply(
-            p, obs, a["conv_strides"], mode)
-        return init, apply
-    raise ValueError(f"no reference network for policy {policy['name']!r}")
+    ``policy`` block (its ``name`` and ``arch``)."""
+    return load(policy["name"], bench_dir).make(policy, obs_shape,
+                                                n_actions)

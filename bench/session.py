@@ -14,6 +14,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from bench import spans as spans_mod
 from bench import trace as trace_mod
 from bench.reference import keys as ref_keys
 from bench.reference import nets
@@ -68,10 +69,11 @@ def ref_hp(spec: dict) -> dict:
     return hp
 
 
-def make_weights(spec: dict, obs_shape, n_actions, seed: int):
+def make_weights(spec: dict, obs_shape, n_actions, seed: int,
+                 bench_dir: Path):
     """The policy's weights from ``seed``, made on the device in one
     jitted call, float32 as the configuration states."""
-    init, _ = nets.make(ref_policy(spec), obs_shape, n_actions)
+    init, _ = nets.make(ref_policy(spec), obs_shape, n_actions, bench_dir)
     return jax.jit(init)(ref_keys.seed_key(seed))
 
 
@@ -116,7 +118,9 @@ class CompileCounter:
 def window(trace_dir: Optional[Path], counter: CompileCounter):
     """The measured window: compiles counted, and with ``trace_dir`` a
     profiler trace with the ``bench.window`` span around it. Yields a
-    dict that holds the trace's reduction once the block has ended."""
+    dict that holds, once the block has ended, the trace's reduction
+    (``bench.trace.reduce``) and the program's spans and scopes in it
+    (``bench.spans.reduce``), both read after the window has closed."""
     out = {}
     counter.count, counter.counting = 0, True
     if trace_dir is None:
@@ -135,8 +139,9 @@ def window(trace_dir: Optional[Path], counter: CompileCounter):
                 yield out
     finally:
         counter.counting = False
-    out["trace"] = trace_mod.reduce(trace_mod.load(
-        trace_mod.find_xplane(str(trace_dir))))
+    planes = trace_mod.load(trace_mod.find_xplane(str(trace_dir)))
+    out["trace"] = trace_mod.reduce(planes)
+    out["trace"].update(spans_mod.reduce(planes))
     shutil.rmtree(trace_dir, ignore_errors=True)
 
 

@@ -51,9 +51,12 @@ def test_lower_precision_control_is_not_correct(name):
     cell = small(name)
     spec = cell.spec_dict()
     from bench.reference import envs as ref_envs
-    env = ref_envs.make(spec["env"]["name"])
-    _, apply = nets.make(S.ref_policy(spec), env.obs_shape, env.n_actions)
-    params0 = S.host(S.make_weights(spec, env.obs_shape, env.n_actions, 3))
+    env = ref_envs.make(spec["env"]["name"], spec["env"].get("kwargs", {}),
+                        cell.bench_dir)
+    _, apply = nets.make(S.ref_policy(spec), env.obs_shape, env.n_actions,
+                         cell.bench_dir)
+    params0 = S.host(S.make_weights(spec, env.obs_shape, env.n_actions, 3,
+                                    cell.bench_dir))
     hp = S.ref_hp(spec)
     rec = hts.simulate(apply, env, params0, hp, SMALL["n_envs"],
                        fit.CHECK_INTERVALS, cell.config["control"])

@@ -47,14 +47,15 @@ def test_op_name_and_collectives():
 def test_reduce_reads_window_busy_and_breakdown():
     planes = {
         "devices": {"/device:TPU:0": {
-            "ops": [(100, 200, "%a = f32[] fusion()"),
-                    (150, 180, "%b = f32[] convolution()"),
-                    (400, 450, "%c = f32[] all-gather-start()"),
-                    (420, 430, "%d = f32[] fusion()")],
+            "ops": [(100, 200, "%a = f32[] fusion()", "jit(step)/a"),
+                    (150, 180, "%b = f32[] convolution()", "jit(step)/b"),
+                    (400, 450, "%c = f32[] all-gather-start()", ""),
+                    (420, 430, "%d = f32[] fusion()", "")],
             "modules": [(90, 210, "jit_step(1)"), (390, 460, "jit_drain(2)")],
         }},
-        "host": {"python": [(0, 1000, "bench.window"),
-                            (210, 380, "bench.save")]},
+        "host": [(210, 380, "fit.save"), (0, 1000, "bench.window"),
+                 (205, 390, "PjitFunction(step)"),
+                 (200, 385, "fit.segment")],
     }
     r = trace.reduce(planes)
     assert r["window_s"] == pytest.approx(1000e-9)
@@ -63,8 +64,9 @@ def test_reduce_reads_window_busy_and_breakdown():
     assert dev["modules_s"]["jit_step"] == pytest.approx(120e-9)
     assert dev["collective_exposed_s"] == pytest.approx(40e-9)
     assert r["device_ops"][0][0] == "a"
-    assert r["idle_gaps"][0] == ["host:no span", pytest.approx(550e-9)]
-    assert ["bench.save", pytest.approx(200e-9)] in r["idle_gaps"]
+    assert r["idle_gaps"][0] == [trace.NO_SPAN, pytest.approx(550e-9)]
+    # the innermost program span on any thread's line, not a JAX span
+    assert ["fit.save", pytest.approx(200e-9)] in r["idle_gaps"]
 
 
 def test_reduce_a_recorded_tpu_trace(tmp_path):
@@ -84,3 +86,47 @@ def test_reduce_a_recorded_tpu_trace(tmp_path):
     assert [t for _, t in ops] == sorted((t for _, t in ops), reverse=True)
     assert sum(t for _, t in r["idle_gaps"]) <= r["window_s"] - r["busy_s"] \
         + 1e-9
+
+
+def test_recorded_gaps_are_named_by_the_programs_spans(tmp_path):
+    """On the trace of the instrumented program, whose spans and window
+    lie on a line named ``python3`` among the host's lines, the window is
+    the ``bench.window`` span and the longest idle gaps are named by the
+    ``fit.`` spans around them."""
+    path = tmp_path / "fit_n64_scoped.xplane.pb"
+    with gzip.open(FIXTURE.parent / "fit_n64_scoped.xplane.pb.gz") as src, \
+            open(path, "wb") as dst:
+        shutil.copyfileobj(src, dst)
+    planes = trace.load(str(path))
+    span = [(s, e) for s, e, n in planes["host"] if n == trace.WINDOW_SPAN]
+    assert len(span) == 1
+    assert trace.window(planes) == span[0]
+    gaps = trace.reduce(planes)["idle_gaps"]
+    names = [n for n, _ in gaps]
+    assert names[:2] == ["fit.save", "fit.resume"]
+    assert all(n.startswith("fit.") for n in names)
+
+
+def test_load_keeps_lines_that_share_a_name(tmp_path):
+    """Python threads' lines may all be named ``python3``: the loader
+    keeps the events of each, so the window and the program's spans are
+    found whichever thread's line holds them."""
+    space = trace._messages()()
+    host = space.planes.add(name="/host:CPU")
+    for key, name in enumerate(("bench.window", "fit.save", "worker")):
+        host.event_metadata.add(key=key + 1).value.name = name
+    main, worker = host.lines.add(name="python3"), host.lines.add(
+        name="python3")
+    main.timestamp_ns = worker.timestamp_ns = 1000
+    main.events.add(metadata_id=1, offset_ps=0, duration_ps=9_000_000)
+    main.events.add(metadata_id=2, offset_ps=2_000_000,
+                    duration_ps=3_000_000)
+    worker.events.add(metadata_id=3, offset_ps=1_000_000,
+                      duration_ps=1_000_000)
+    path = tmp_path / "two_lines.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+    planes = trace.load(str(path))
+    assert sorted(planes["host"]) == [(1000.0, 10000.0, "bench.window"),
+                                      (2000.0, 3000.0, "worker"),
+                                      (3000.0, 6000.0, "fit.save")]
+    assert trace.window(planes) == (1000.0, 10000.0)
