@@ -23,8 +23,11 @@ not approximately, bit-for-bit. Floating-point addition is commutative
 but not associative, so the contract is a REDUCTION-ORDER contract:
 
   * the gradient is computed per env (vmap of grad over width-1 env
-    slices; per-env grads are bit-stable across batch widths because
-    every model forward is row-independent);
+    slices, one microbatch block at a time when the blocks hold two or
+    more envs). Every model forward is row-independent, but a per-env
+    gradient's bits can depend on the vmap's width: the mlp policy's do
+    not, the conv policy's do (DESIGN.md §12), so its contract holds
+    at a fixed geometry;
   * per-env gradients are combined by the adjacent-pairwise tree sum
     (``pairwise_tree_sum``) over the GLOBAL env index, accumulated in
     fp32;

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 from typing import Callable, Optional
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -72,37 +73,47 @@ def make_grad_sum_fn(policy_apply: Callable, cfg: HTSConfig,
     gradients over the local trajectory — the geometry-invariant half
     of the learner's gradient (repro.core.batch, DESIGN.md §12).
 
-    Per-env gradients (ONE vmap of grad over width-1 env slices, always
-    at the full local width) are cast to fp32 and combined by the
-    adjacent-pairwise tree over the env index. With
-    ``grad_accumulation = A > 1`` the stacked per-env grads are reduced
-    hierarchically — per-microbatch-block subtree sums, then the tree
-    over the A block sums — which is bit-identical to the flat tree
-    (power-of-two blocks are exact subtrees: same adds, same order) and
-    mirrors exactly what physically-separated replicas/microbatches
-    compute. Note the deliberate absence of a divide: replicas combine
+    Per-env gradients (a vmap of grad over width-1 env slices) are cast
+    to fp32 and combined by the adjacent-pairwise tree over the env
+    index. Note the deliberate absence of a divide: replicas combine
     SUMS, and the single divide by the global batch happens in
     make_grad_fn / make_learner_update.
 
-    With a power-of-two local env count the trajectory's env axis is
-    permuted to bit-reversed order before the vmap (a gather on the
-    trajectory, never on the per-env stack), and ``halves_tree_sum``
-    reduces the flat stack in contiguous halves: the same adds in the
-    same order, so the same float as the strided tree, with or without
-    accumulation (blocks are exact subtrees). Any other count takes
-    the strided tree. The scopes ``tree_halves`` and ``tree_strided``
-    inside ``grad_reduce`` say which path ran.
+    With ``grad_accumulation = A > 1`` and blocks of ``n_local / A >= 2``
+    envs, the blocks run in sequence, in a ``lax.scan``: a vmap of grad
+    over one contiguous block, that block's subtree sum, then the next,
+    so one block's stack of per-env gradients is live at a time. The A
+    block sums are then combined by the tree: power-of-two blocks are
+    exact subtrees, so these are the flat tree's adds in its order.
+    With A = 1, or blocks of one env, ONE vmap runs at the full local
+    width: a width-1 vmap inside ``lax.scan`` is simplified to the
+    unbatched lowering (gemv), whose products add in another order.
 
-    The backward is deliberately NOT scanned block-by-block: a width-1
-    vmap inside ``lax.scan`` gets simplified to the unbatched lowering,
-    whose matmuls take a different (gemv) accumulation path than the
-    batched ones — per-env grads then differ in the last bits between
-    micro_batch=1 and wider geometries. One full-width vmap keeps the
-    lowering — and therefore every per-env gradient — identical across
-    all factorizations of the same local slice."""
+    A per-env gradient is row-independent, but its lowering depends on
+    the vmap's width and, on XLA:CPU, on whether it runs in a loop body
+    (where a convolution keeps the layout autodiff gave it and takes
+    another kernel). The mlp policy's bits move with neither; the conv
+    policy's move with both, on the CPU as on a TPU. So the mlp policy's
+    sum is bit-equal across every geometry, while the conv policy's is
+    bit-exact for one geometry, across segmentations and runtimes, and
+    is checked against a float32 reference across geometries.
+
+    With a power-of-two block (the whole local slice, without blocks)
+    the trajectory's env axis is permuted to bit-reversed order within
+    each block before the vmap (a gather on the trajectory, never on the
+    per-env stack), and ``halves_tree_sum`` reduces the stack in
+    contiguous halves: the same adds in the same order, so the same
+    float as the strided tree. Any other width takes the strided tree.
+    The scopes ``tree_halves`` and ``tree_strided`` inside
+    ``grad_reduce`` say which path ran."""
     g1 = jax.grad(
         lambda p, traj: _interval_loss(policy_apply, p, traj, cfg)[0],
         has_aux=False)
+
+    def per_env_grads(params, per):
+        with jax.named_scope(spans.PER_ENV_GRAD):
+            g = jax.vmap(g1, in_axes=(None, 0))(params, per)
+            return jax.tree.map(lambda x: x.astype(jnp.float32), g)
 
     def grad_sum(params, traj):
         per = _split_envs(traj)
@@ -112,28 +123,33 @@ def make_grad_sum_fn(policy_apply: Callable, cfg: HTSConfig,
             raise ValueError(
                 f"grad_accumulation={A} does not divide the local env "
                 f"count {n_local}")
-        halves = (n_local & (n_local - 1)) == 0
+        blocks = A if A > 1 and n_local // A >= 2 else 1
+        width = n_local // blocks
+        halves = (width & (width - 1)) == 0
+        tree = spans.TREE_HALVES if halves else spans.TREE_STRIDED
+        block_sum = halves_tree_sum if halves else pairwise_tree_sum
         if halves:
-            order = bit_reversed_order(n_local)
+            # bit-reversed order within each contiguous block
+            order = (np.arange(blocks)[:, None] * width
+                     + bit_reversed_order(width)).reshape(-1)
             with jax.named_scope(spans.GRAD_REDUCE), \
-                    jax.named_scope(spans.TREE_HALVES):
+                    jax.named_scope(tree):
                 per = jax.tree.map(lambda x: x[order], per)
-        with jax.named_scope(spans.PER_ENV_GRAD):
-            per_env = jax.vmap(g1, in_axes=(None, 0))(params, per)
-            per_env = jax.tree.map(lambda g: g.astype(jnp.float32),
-                                   per_env)
-        with jax.named_scope(spans.GRAD_REDUCE):
-            if halves:
-                with jax.named_scope(spans.TREE_HALVES):
-                    return jax.tree.map(halves_tree_sum, per_env)
-            with jax.named_scope(spans.TREE_STRIDED):
-                if A <= 1:
-                    return jax.tree.map(pairwise_tree_sum, per_env)
-                sums = jax.tree.map(
-                    lambda g: jax.vmap(pairwise_tree_sum)(
-                        g.reshape((A, n_local // A) + g.shape[1:])),
-                    per_env)
+        if blocks > 1:
+            def one_block(_, block):
+                g = per_env_grads(params, block)
+                with jax.named_scope(spans.GRAD_REDUCE), \
+                        jax.named_scope(tree):
+                    return None, jax.tree.map(block_sum, g)
+
+            _, sums = jax.lax.scan(one_block, None, jax.tree.map(
+                lambda x: x.reshape((blocks, width) + x.shape[1:]), per))
+            with jax.named_scope(spans.GRAD_REDUCE), \
+                    jax.named_scope(tree):
                 return jax.tree.map(pairwise_tree_sum, sums)
+        per_env = per_env_grads(params, per)
+        with jax.named_scope(spans.GRAD_REDUCE), jax.named_scope(tree):
+            return jax.tree.map(block_sum, per_env)
 
     return grad_sum
 
@@ -361,11 +377,12 @@ class MeshRuntime(ScanRuntimeBase):
 
     ``batch`` (a ``repro.core.batch.BatchConfig``) is accepted as pure
     factorization bookkeeping: a single fused program reproduces an
-    (n_replicas x grad_accumulation) geometry bit-exactly by scanning
-    the gradient over ``chunks = grad_accumulation * n_replicas``
-    microbatch blocks — the canonical reduction is geometry-invariant,
-    so the mesh runtime is the single-process oracle for any validated
-    multi-replica run."""
+    (n_replicas x grad_accumulation) geometry by running the gradient
+    over ``chunks = grad_accumulation * n_replicas`` microbatch blocks
+    in sequence — the canonical reduction is geometry-invariant, so the
+    mesh runtime is the single-process oracle for any validated
+    multi-replica run (bit-exact where the per-env bits do not depend
+    on the block's width, as the mlp policy's; DESIGN.md §12)."""
 
     name = "mesh"
 

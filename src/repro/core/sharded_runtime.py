@@ -14,9 +14,10 @@ Replica count comes from the batch geometry
 sizes the data axis to EXACTLY that many devices (erroring when the
 platform has fewer); the legacy default (``n_replicas=None``) keeps the
 pre-BatchConfig behavior of spanning every local device. Within each
-replica, ``grad_accumulation`` microbatch blocks are scanned before the
-cross-replica combine — grads cross replicas once per logical step,
-never per microbatch.
+replica, ``grad_accumulation`` microbatch blocks of two or more envs run
+in sequence (``mesh_runtime.make_grad_sum_fn``), so one block's per-env
+gradients are live at a time, before the cross-replica combine — grads
+cross replicas once per logical step, never per microbatch.
 
 Determinism is preserved across device counts AND processes: rollout
 env ids are offset by ``axis_index('data') * n_envs_local``, so env

@@ -43,24 +43,23 @@ SPANS = (FIT_SEGMENT, FIT_CAPTURE, FIT_STREAM, FIT_SAVE, FIT_RESUME,
          HOST_GRAD_DISPATCH, HOST_APPLY_DISPATCH, HOST_ACTOR_DISPATCH,
          HOST_STEP_DISPATCH)
 
-# ---- device scopes (core/mesh_runtime.py, core/rollout.py)
+# ---- device scopes (core/mesh_runtime.py, core/rollout.py, envs/device)
 ROLLOUT = "hts.rollout"          # the interval's rollout half
 ACTOR_FORWARD = "actor_forward"  # policy forward and sampling
 ENV_STEP = "env_step"            # the batched env step
+RENDER = "render"                # a device env's frame rendering
 LEARNER = "hts.learner"          # the interval's learner half
 PER_ENV_GRAD = "per_env_grad"    # vmap of the width-1 gradient
 GRAD_REDUCE = "grad_reduce"      # pairwise tree sums, all-gather
 OPTIMIZER = "optimizer"          # delayed_grad.update
 DRAIN = "hts.drain"              # the reporting-only trailing pass
 
-SCOPES = (ROLLOUT, ACTOR_FORWARD, ENV_STEP, LEARNER, PER_ENV_GRAD,
-          GRAD_REDUCE, OPTIMIZER, DRAIN)
-
-# ---- inside grad_reduce, the path the per-env tree sum took
-# (core/batch.py); kept out of SCOPES, the names the benchmark's trace
-# reader knows
+# inside grad_reduce, the path the per-env tree sum took (core/batch.py)
 TREE_HALVES = "tree_halves"      # contiguous halves, power-of-two count
 TREE_STRIDED = "tree_strided"    # strided pairs, any other count
+
+SCOPES = (ROLLOUT, ACTOR_FORWARD, ENV_STEP, RENDER, LEARNER, PER_ENV_GRAD,
+          GRAD_REDUCE, TREE_HALVES, TREE_STRIDED, OPTIMIZER, DRAIN)
 
 
 def span(name: str):

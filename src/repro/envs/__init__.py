@@ -29,6 +29,7 @@ _REGISTRY: Dict[str, Callable[..., Any]] = {}
 _LAZY: Dict[str, tuple] = {
     "catch": ("repro.envs.catch", "make"),
     "gridmaze": ("repro.envs.gridmaze", "make"),
+    "gridmaze84": ("repro.envs.gridmaze84", "make"),
     "football": ("repro.envs.football", "make"),
     "token": ("repro.envs.token_env", "make"),
     "token_stream": ("repro.data.pipeline", "TokenStream"),
@@ -38,6 +39,7 @@ _LAZY: Dict[str, tuple] = {
     # entries exist for direct construction and tests.
     "catch_device": ("repro.envs.device.catch", "make"),
     "gridmaze_device": ("repro.envs.device.gridmaze", "make"),
+    "gridmaze84_device": ("repro.envs.device.gridmaze84", "make"),
 }
 
 

@@ -91,6 +91,7 @@ _REGISTRY: Dict[str, Callable[..., DeviceEnv]] = {}
 _LAZY: Dict[str, tuple] = {
     "catch": ("repro.envs.device.catch", "make"),
     "gridmaze": ("repro.envs.device.gridmaze", "make"),
+    "gridmaze84": ("repro.envs.device.gridmaze84", "make"),
 }
 
 

@@ -3,9 +3,10 @@
 State layout: ``{"r", "c", "t"}``, each ``(n,)`` int32 — the stacked
 pytree of the vmapped host env, capsule-compatible across backends.
 
-Fully deterministic, so the whole step is broadcast arithmetic: moves
-are a gather from the shared MOVES table, wall collisions a batched
-advanced-index lookup into the shared walls board, and the 3-channel
+Fully deterministic, so the whole step is broadcast arithmetic: the
+host env's own rules (``gridmaze.move``) run batched — moves are a
+gather from the shared MOVES table, wall collisions a batched
+advanced-index lookup into the walls board — and the 3-channel
 observation is assembled from one-hot comparison masks plus broadcast
 copies of the static walls/goal planes — no scatter anywhere.
 
@@ -22,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.envs.gridmaze import HORIZON, MOVES, N, WALLS, resolve_board
+from repro.envs.gridmaze import N, move, resolve_board
 from repro.envs.device import DeviceEnv, device_autoreset
 
 
@@ -52,16 +53,8 @@ def _batched_fns(walls: jnp.ndarray, goal):
 
     def step(state, actions, keys):
         del keys
-        mv = MOVES[actions]                     # (n, 2) gather
-        nr = jnp.clip(state["r"] + mv[:, 0], 0, N - 1)
-        nc = jnp.clip(state["c"] + mv[:, 1], 0, N - 1)
-        blocked = walls[nr, nc] > 0             # batched advanced indexing
-        nr = jnp.where(blocked, state["r"], nr)
-        nc = jnp.where(blocked, state["c"], nc)
-        t = state["t"] + 1
-        at_goal = (nr == gr) & (nc == gc)
-        done = at_goal | (t >= HORIZON)
-        reward = jnp.where(at_goal, 1.0, -0.01)
+        nr, nc, t, reward, done = move(walls, goal, state["r"], state["c"],
+                                       state["t"], actions)
         ns = {"r": nr, "c": nc, "t": t}
         return ns, obs(ns), reward, done.astype(jnp.float32)
 

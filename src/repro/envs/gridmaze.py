@@ -92,6 +92,24 @@ def sample_scenario(seed: int) -> Tuple[np.ndarray, Tuple[int, int]]:
         return walls, (int(goal[0]), int(goal[1]))
 
 
+def move(walls: jnp.ndarray, goal: Tuple[int, int], r, c, t, action):
+    """The rules of one step from ``(r, c)`` at step ``t``, for one env
+    or a batch alike: move, or stay put where the move is blocked; +1 at
+    the goal, -0.01 otherwise; done (a bool) at the goal or at the
+    horizon. Returns ``(r, c, t, reward, done)``."""
+    mv = MOVES[action]
+    nr = jnp.clip(r + mv[..., 0], 0, N - 1)
+    nc = jnp.clip(c + mv[..., 1], 0, N - 1)
+    blocked = walls[nr, nc] > 0
+    nr = jnp.where(blocked, r, nr)
+    nc = jnp.where(blocked, c, nc)
+    t = t + 1
+    at_goal = (nr == goal[0]) & (nc == goal[1])
+    done = at_goal | (t >= HORIZON)
+    reward = jnp.where(at_goal, 1.0, -0.01)
+    return nr, nc, t, reward, done
+
+
 def _scalar_fns(walls: jnp.ndarray, goal: Tuple[int, int]):
     """The scalar reset/step pair over one (walls, goal) board."""
     gr, gc = goal
@@ -111,16 +129,8 @@ def _scalar_fns(walls: jnp.ndarray, goal: Tuple[int, int]):
 
     def step(state, action, key):
         del key
-        mv = MOVES[action]
-        nr = jnp.clip(state["r"] + mv[0], 0, N - 1)
-        nc = jnp.clip(state["c"] + mv[1], 0, N - 1)
-        blocked = walls[nr, nc] > 0
-        nr = jnp.where(blocked, state["r"], nr)
-        nc = jnp.where(blocked, state["c"], nc)
-        t = state["t"] + 1
-        at_goal = (nr == gr) & (nc == gc)
-        done = at_goal | (t >= HORIZON)
-        reward = jnp.where(at_goal, 1.0, -0.01)
+        nr, nc, t, reward, done = move(walls, goal, state["r"], state["c"],
+                                       state["t"], action)
         ns = {"r": nr, "c": nc, "t": t}
         return ns, obs(ns), reward, done.astype(jnp.float32)
 

@@ -13,7 +13,8 @@ Three layers of pinning:
     factorization bookkeeping), host (accumulated gradient pass), and
     sharded (real 2-device data parallelism, subprocess) runtimes —
     including a checkpoint capsule restored onto a different replica
-    count;
+    count (the mlp policy; the conv policy's sums move with the block
+    width within float32 rounding);
   * multi-process — a 2-process ``jax.distributed`` run
     (repro.launch.distributed) produces the single-process mesh
     digest, bit-exact, on both processes.
@@ -38,7 +39,7 @@ from repro.core import mesh_runtime, spans
 from repro.core.batch import (BatchConfig, bit_reversed_order,
                               halves_tree_sum, pairwise_tree_sum)
 from repro.core.engine import HTSConfig
-from repro.envs import catch, gridmaze
+from repro.envs import catch, gridmaze, gridmaze84
 from repro.optim import rmsprop
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -225,19 +226,26 @@ def test_halves_tree_sum_is_pairwise_tree_sum_bit_for_bit(n, monkeypatch):
 
 def _old_grad_sum(policy_apply, cfg, A):
     """The strided formulation: vmap in natural env order, then the
-    adjacent-pairwise tree (per block, then over the blocks, for A > 1)."""
+    adjacent-pairwise tree; for A > 1, block by block in sequence (a
+    scan over blocks of n / A envs, as ``make_grad_sum_fn`` runs them),
+    then the tree over the block sums."""
     g1 = jax.grad(lambda p, t: mesh_runtime._interval_loss(
         policy_apply, p, t, cfg)[0])
 
+    def block_sum(params, per):
+        g = jax.vmap(g1, in_axes=(None, 0))(params, per)
+        return jax.tree.map(
+            lambda x: pairwise_tree_sum(x.astype(jnp.float32)), g)
+
     def grad_sum(params, traj):
         per = mesh_runtime._split_envs(traj)
-        g = jax.vmap(g1, in_axes=(None, 0))(params, per)
-        g = jax.tree.map(lambda x: x.astype(jnp.float32), g)
         if A == 1:
-            return jax.tree.map(pairwise_tree_sum, g)
-        n = jax.tree.leaves(g)[0].shape[0]
-        sums = jax.tree.map(lambda x: jax.vmap(pairwise_tree_sum)(
-            x.reshape((A, n // A) + x.shape[1:])), g)
+            return block_sum(params, per)
+        n = jax.tree.leaves(per)[0].shape[0]
+        _, sums = jax.lax.scan(
+            lambda _, b: (None, block_sum(params, b)), None,
+            jax.tree.map(lambda x: x.reshape((A, n // A) + x.shape[1:]),
+                         per))
         return jax.tree.map(pairwise_tree_sum, sums)
     return grad_sum
 
@@ -261,9 +269,10 @@ def _random_traj(n, alpha, seed):
                                  (48, 4)])
 def test_grad_sum_keeps_the_strided_bits(n, A):
     """``make_grad_sum_fn`` on the cnn policy gives the strided
-    formulation's gradient sum bit for bit: bit-reversed env order and
-    contiguous halves at a power-of-two count (with or without
-    accumulation), the strided tree itself at 48 envs."""
+    formulation's gradient sum bit for bit, at the same geometry:
+    bit-reversed env order and contiguous halves at a power-of-two block
+    (the whole slice without accumulation), the strided tree itself at
+    blocks of 48, 24 and 12 envs."""
     policy = _cnn()
     params = policy.init(jax.random.key(1))
     cfg = HTSConfig(alpha=5, n_envs=n, seed=3)
@@ -279,6 +288,86 @@ def test_grad_sum_keeps_the_strided_bits(n, A):
         assert bool(under) == (name == path), name
         assert all(spans.GRAD_REDUCE in p.split("/")[:p.split("/").index(
             name)] for p in under), name
+
+
+@pytest.mark.parametrize("n", [8, 64])
+def test_conv_blocks_move_the_bits_within_rounding(n):
+    """The cost of running blocks in sequence for the conv policy: on
+    9x9x3 boards its sum at A = 2 is not the full-width sum bit for bit
+    (its per-env bits move with the vmap's width and the loop body), so
+    a conv run continued onto another geometry leaves the bits of the
+    first; the two sums agree to float32 rounding."""
+    policy = _cnn()
+    params = policy.init(jax.random.key(1))
+    cfg = HTSConfig(alpha=5, n_envs=n, seed=3)
+    traj = _random_traj(n, cfg.alpha, seed=n)
+    got, want = (jax.jit(mesh_runtime.make_grad_sum_fn(
+        policy.apply, cfg, A))(params, traj) for A in (2, 1))
+    assert not _same_bits(got, want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(w).max()))
+
+
+def _frames_traj(n, alpha, seed):
+    """A random interval of 84x84x4 uint8 frames (levels 0-3), as the
+    gridmaze84 env emits them."""
+    rng = np.random.default_rng(seed)
+    frames = lambda *lead: jnp.asarray(
+        rng.integers(0, 4, lead + (84, 84, 4)), jnp.uint8)
+    traj = _random_traj(n, alpha, seed)
+    traj.update(obs=frames(alpha, n), bootstrap_obs=frames(n))
+    return traj
+
+
+def _frames_policy(name):
+    return models.get_policy(name, gridmaze84.make())
+
+
+@pytest.mark.parametrize("n", [8, 16])
+@pytest.mark.parametrize("A", [2, 4])
+@pytest.mark.parametrize("name", ["mlp", "cnn"])
+def test_blocks_in_sequence_keep_the_sum(name, n, A):
+    """Blocks of n / A envs run in sequence (a scan over blocks) on
+    84x84x4 frames. The mlp policy's sum equals the one full-width vmap's
+    bit for bit. The conv trunk's per-env bits move with the vmap's
+    width, so its sum equals the strided formulation at the same blocks
+    bit for bit, and the full-width sum to float32 rounding."""
+    policy = _frames_policy(name)
+    params = policy.init(jax.random.key(1))
+    cfg = HTSConfig(alpha=5, n_envs=n, seed=3)
+    traj = _frames_traj(n, cfg.alpha, seed=n + A)
+    blocks = jax.jit(mesh_runtime.make_grad_sum_fn(policy.apply, cfg, A))
+    flat = jax.jit(mesh_runtime.make_grad_sum_fn(policy.apply, cfg, 1))
+    got, want = blocks(params, traj), flat(params, traj)
+    if name == "mlp":
+        assert _same_bits(got, want)
+    else:
+        strided = jax.jit(_old_grad_sum(policy.apply, cfg, A))
+        assert _same_bits(got, strided(params, traj))
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(g, w, rtol=1e-4,
+                                       atol=1e-5 * float(jnp.abs(w).max()))
+    text = blocks.lower(params, traj).compile().as_text()
+    assert "while" in text
+
+
+@pytest.mark.parametrize("name", ["mlp", "cnn"])
+def test_blocks_in_sequence_hold_one_blocks_stack(name):
+    """The per-env stack dominates these programs (16 envs of 84x84x4
+    frames: 14.5 MB of mlp and 6.7 MB of conv-trunk gradients per env);
+    with four blocks in sequence the compiled program needs at most half
+    the temporary memory of the one full-width vmap."""
+    policy = _frames_policy(name)
+    params = policy.init(jax.random.key(1))
+    cfg = HTSConfig(alpha=5, n_envs=16, seed=3)
+    traj = _frames_traj(16, cfg.alpha, seed=0)
+    temp = {A: jax.jit(mesh_runtime.make_grad_sum_fn(policy.apply, cfg, A))
+            .lower(params, traj).compile().memory_analysis()
+            .temp_size_in_bytes for A in (1, 4)}
+    stack = 16 * sum(x.size for x in jax.tree.leaves(params)) * 4
+    assert temp[1] >= stack // 2, temp
+    assert temp[4] <= temp[1] // 2, temp
 
 
 # --------------------------------------------------- spec / fingerprint

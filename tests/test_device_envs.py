@@ -31,7 +31,7 @@ try:
 except ImportError:        # container skips; CI installs hypothesis
     HAVE_HYPOTHESIS = False
 
-PORTED = ["catch", "gridmaze"]
+PORTED = ["catch", "gridmaze", "gridmaze84"]
 
 
 # ------------------------------------------------------------- registry

@@ -123,3 +123,38 @@ def test_cnn_learner_update_compiles_at_full_width(one_chip):
     assert mem.temp_size_in_bytes <= 512 * 354_309 * 4
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 16e9
+
+
+def test_paper_trunk_learner_runs_blocks_in_sequence(one_chip):
+    """The fused learner half of the paper's trunk at its published
+    widths (8x8/4, 4x4/2, 3x3/1, fc 512: 1,686,693 parameters) on 2048
+    envs of 84x84x4 frames, with two gradient blocks of 1024 envs in
+    sequence. The per-env stack of all 2048 envs is 13.8 GB; the
+    compiled program holds one block's, and fits the chip."""
+    from repro import envs, models, optim
+    from repro.core import mesh_runtime
+    from repro.core.engine import HTSConfig
+    from repro.envs.device import batched_env
+    env = envs.get_env("gridmaze84")
+    policy = models.get_policy("cnn", env)
+    opt = optim.get_optimizer("rmsprop", lr=7e-4, eps=1e-5)
+    cfg = HTSConfig(alpha=5, n_envs=2048, env_backend="device")
+    venv = batched_env(env, cfg.n_envs, cfg.env_backend)
+    dg, _, _, traj, _ = jax.eval_shape(
+        lambda: mesh_runtime.init_carry(
+            policy.init(jax.random.key(0)), opt, venv, cfg, policy.apply))
+    n_params = sum(x.size for x in jax.tree.leaves(dg.params))
+    assert n_params == 1_686_693
+    learn = mesh_runtime.make_learner_update(policy.apply, opt, cfg,
+                                             grad_accumulation=2)
+    skip = jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(learn).lower(_on(one_chip, dg), _on(one_chip, traj),
+                                    skip).compile()
+    mem = compiled.memory_analysis()
+    block = 1024 * n_params * 4
+    # one block's fc gradients (1024 x 3136 x 512 x 4 B) are held whole;
+    # a second block's stack is never live beside the first
+    assert mem.temp_size_in_bytes >= 1024 * 3136 * 512 * 4
+    assert mem.temp_size_in_bytes < 2 * block
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 14e9
