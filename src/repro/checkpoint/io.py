@@ -15,6 +15,16 @@ silently reset the one-step delay), or an engine ``TrainState`` capsule
 runtime's shard_map programs) are gathered with ``jax.device_get`` before
 writing, so a checkpoint taken on an N-device mesh restores on any
 device count.
+
+``save`` moves every leaf to the host in one batched ``device_get``.
+A leaf on one device whose bytes fill whole (8, 128) tiles of 32-bit
+words goes as such words, shaped (n, 128). A TPU lays an array out in
+tiles, and for a small minor dimension it may make another one minor:
+a uint8 frame stack (n_envs, 84, 84, 4) lies with its env axis minor,
+so a row-major host copy of it is reordered byte by byte on the host.
+(n, 128) words lie row-major on the device and are copied as they lie.
+The host views the words back as the leaf (no copy); the file is the
+same either way.
 """
 from __future__ import annotations
 
@@ -27,8 +37,13 @@ from typing import Any
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+from repro.core import spans
 
 FORMAT_VERSION = 1
+# one (8, 128) tile of 32-bit words, in bytes
+_TILE_BYTES = 8 * 128 * 4
 
 
 class CheckpointCorrupt(RuntimeError):
@@ -42,24 +57,85 @@ class CheckpointCorrupt(RuntimeError):
     structural mismatch is a caller error no amount of retrying fixes."""
 
 
-def _to_numpy(leaf) -> np.ndarray:
-    # device_get gathers sharded jax.Arrays to one host buffer; plain
-    # numpy/python leaves pass through
-    if isinstance(leaf, jax.Array):
-        leaf = jax.device_get(leaf)
-    return np.asarray(leaf)
+def _nbytes(leaf) -> int:
+    if not hasattr(leaf, "dtype"):          # a python scalar
+        leaf = np.asarray(leaf)
+    return int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+
+
+def packs(leaf) -> bool:
+    """Whether ``save`` moves ``leaf`` (a ``jax.Array`` or a
+    ``ShapeDtypeStruct``) to the host as (n, 128) 32-bit words: one
+    device holds it, its dtype is an int, uint or float of 1, 2 or 4
+    bytes, and its bytes fill whole (8, 128) tiles of words. Sharded
+    leaves and numpy leaves go as they are."""
+    if not isinstance(leaf, (jax.Array, jax.ShapeDtypeStruct)):
+        return False
+    sharding = getattr(leaf, "sharding", None)
+    if sharding is not None and len(sharding.device_set) != 1:
+        return False
+    dtype = np.dtype(leaf.dtype)
+    nbytes = _nbytes(leaf)
+    return ((dtype.kind in "iuf" or dtype == jnp.bfloat16)
+            and dtype.itemsize in (1, 2, 4)
+            and nbytes > 0 and nbytes % _TILE_BYTES == 0)
+
+
+def split(leaves) -> tuple[int, int]:
+    """(packed, plain) bytes of ``leaves``, as ``packs`` divides them."""
+    packed = plain = 0
+    for leaf in leaves:
+        nbytes = _nbytes(leaf)
+        if packs(leaf):
+            packed += nbytes
+        else:
+            plain += nbytes
+    return packed, plain
+
+
+def _words(x):
+    """``x`` as (n, 128) uint32, 4 bytes or 2 half-words to a word in
+    the order ``lax.bitcast_convert_type`` groups them (the host's
+    ``.view`` order)."""
+    per = 4 // x.dtype.itemsize
+    if per > 1:
+        x = x.reshape(-1, per)
+    return lax.bitcast_convert_type(x, jnp.uint32).reshape(-1, 128)
+
+
+# one program per capsule signature: jit caches it by the leaves'
+# shapes, dtypes and shardings
+_pack = jax.jit(lambda leaves: [_words(x) for x in leaves])
+
+
+def _gather(leaves) -> list[np.ndarray]:
+    """Every leaf as a host array, through one ``device_get`` over the
+    list, so the copies are issued together; the leaves that ``packs``
+    go as words and are viewed back as themselves."""
+    packed = [i for i, leaf in enumerate(leaves) if packs(leaf)]
+    sent = list(leaves)
+    if packed:
+        for i, w in zip(packed, _pack([leaves[i] for i in packed])):
+            sent[i] = w
+    got = [np.asarray(a) for a in jax.device_get(sent)]
+    for i in packed:
+        got[i] = got[i].view(leaves[i].dtype).reshape(leaves[i].shape)
+    return got
 
 
 def save(path: str, tree: Any, metadata: dict | None = None) -> None:
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     leaves, treedef = jax.tree_util.tree_flatten(tree)
+    packed, plain = split(leaves)
+    with spans.span(spans.FIT_SAVE_GATHER, packed_bytes=packed,
+                    plain_bytes=plain):
+        host = _gather(leaves)
     # numpy's savez has no bf16 cast path: store bf16 leaves as f32
     # (lossless upcast) and restore back to the reference dtype.
     arrays = {}
     dtypes, shapes, crcs = [], [], []
-    for i, a in enumerate(leaves):
-        arr = _to_numpy(a)
+    for i, arr in enumerate(host):
         dtypes.append(str(arr.dtype))
         shapes.append(list(arr.shape))
         if arr.dtype.name == "bfloat16":
@@ -67,8 +143,10 @@ def save(path: str, tree: Any, metadata: dict | None = None) -> None:
         # per-leaf checksum of the STORED bytes (post-upcast), so a
         # flipped bit or truncated page inside the zip is detected at
         # restore as CheckpointCorrupt naming the leaf, not as silently
-        # wrong parameters
-        crcs.append(zlib.crc32(np.ascontiguousarray(arr).tobytes()))
+        # wrong parameters. crc32 reads the buffer in place: the value
+        # of ``.tobytes()``, without its copy
+        crcs.append(zlib.crc32(
+            np.ascontiguousarray(arr).reshape(-1).view(np.uint8)))
         arrays[f"leaf_{i}"] = arr
     manifest = {
         "version": FORMAT_VERSION,

@@ -28,6 +28,7 @@ FIT_SEGMENT = "fit.segment"      # runtime.run_from
 FIT_CAPTURE = "fit.capture"      # runtime.state()
 FIT_STREAM = "fit.stream"        # on_interval and ReturnStream.extend
 FIT_SAVE = "fit.save"            # checkpoint write and prune
+FIT_SAVE_GATHER = "fit.save_gather"  # checkpoint/io: leaves to the host
 FIT_RESUME = "fit.resume"        # restore of the newest checkpoint
 FIT_RECOVER = "fit.recover"      # supervisor restore after a failure
 # ---- host spans: the threaded host runtime (core/host_runtime.py)
@@ -38,8 +39,8 @@ HOST_APPLY_DISPATCH = "host.apply_dispatch"      # coordinator
 HOST_ACTOR_DISPATCH = "host.actor_dispatch"      # actor threads, profile on
 HOST_STEP_DISPATCH = "host.step_dispatch"        # stepper, profile on
 
-SPANS = (FIT_SEGMENT, FIT_CAPTURE, FIT_STREAM, FIT_SAVE, FIT_RESUME,
-         FIT_RECOVER, HOST_LEARNER_DRAIN, HOST_INTERVAL_BARRIER,
+SPANS = (FIT_SEGMENT, FIT_CAPTURE, FIT_STREAM, FIT_SAVE, FIT_SAVE_GATHER,
+         FIT_RESUME, FIT_RECOVER, HOST_LEARNER_DRAIN, HOST_INTERVAL_BARRIER,
          HOST_GRAD_DISPATCH, HOST_APPLY_DISPATCH, HOST_ACTOR_DISPATCH,
          HOST_STEP_DISPATCH)
 
@@ -62,9 +63,10 @@ SCOPES = (ROLLOUT, ACTOR_FORWARD, ENV_STEP, RENDER, LEARNER, PER_ENV_GRAD,
           GRAD_REDUCE, TREE_HALVES, TREE_STRIDED, OPTIMIZER, DRAIN)
 
 
-def span(name: str):
-    """A host span named ``name`` around a ``with`` block."""
-    return jax.profiler.TraceAnnotation(name)
+def span(name: str, **args):
+    """A host span named ``name`` around a ``with`` block; ``args``
+    ride on it as the TraceMe's arguments."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 _OFF = contextlib.nullcontext()

@@ -158,3 +158,37 @@ def test_paper_trunk_learner_runs_blocks_in_sequence(one_chip):
     assert mem.temp_size_in_bytes < 2 * block
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < 14e9
+
+
+def test_checkpoint_pack_gives_rows_of_words(one_chip):
+    """``checkpoint/io``'s pack program on the capsule of the paper's
+    trunk at 2048 envs of 84x84x4 frames: every output is (n, 128)
+    uint32 in row-major (8, 128) tiles, a layout the device-to-host
+    copy moves as it lies, and the program fits the chip."""
+    from repro import envs, models, optim
+    from repro.checkpoint import io as ckpt_io
+    from repro.core import mesh_runtime
+    from repro.core.engine import HTSConfig
+    from repro.envs.device import batched_env
+    env = envs.get_env("gridmaze84")
+    policy = models.get_policy("cnn", env)
+    opt = optim.get_optimizer("rmsprop", lr=7e-4, eps=1e-5)
+    cfg = HTSConfig(alpha=5, n_envs=2048, env_backend="device")
+    venv = batched_env(env, cfg.n_envs, cfg.env_backend)
+    carry = jax.eval_shape(
+        lambda: mesh_runtime.init_carry(
+            policy.init(jax.random.key(0)), opt, venv, cfg, policy.apply))
+    leaves = jax.tree.leaves(_on(one_chip, carry))
+    packed = [x for x in leaves if ckpt_io.packs(x)]
+    n_bytes, _ = ckpt_io.split(leaves)
+    compiled = ckpt_io._pack.lower(packed).compile()
+    for fmt in compiled.output_formats:
+        assert fmt.layout.major_to_minor == (0, 1)
+        assert fmt.layout.tiling == ((8, 128),)
+    outs = jax.eval_shape(ckpt_io._pack, packed)
+    assert all(o.dtype == jnp.uint32 and o.shape[1:] == (128,)
+               for o in outs)
+    assert sum(o.size * 4 for o in outs) == n_bytes
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 4e9

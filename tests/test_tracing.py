@@ -68,16 +68,16 @@ def test_fit_opens_its_spans_in_order(tmp_path, monkeypatch):
     opened = []
     real = spans.span
 
-    def recording(name):
+    def recording(name, **args):
         opened.append(name)
-        return real(name)
+        return real(name, **args)
 
     monkeypatch.setattr(spans, "span", recording)
     rt = _make("mesh")
     trainer = Trainer(rt, checkpoint_dir=str(tmp_path), ckpt_every=1)
     trainer.fit(2)
     seg = [spans.FIT_SEGMENT, spans.FIT_STREAM, spans.FIT_CAPTURE,
-           spans.FIT_SAVE]
+           spans.FIT_SAVE, spans.FIT_SAVE_GATHER]
     assert opened == seg * 2
     opened.clear()
     trainer.fit(3, resume=True)
